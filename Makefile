@@ -3,9 +3,9 @@ ARTIFACTS := artifacts
 
 .PHONY: test lint bench-smoke bench trace-demo
 
-# tier-1 verify (see ROADMAP.md)
+# tier-1 verify (see ROADMAP.md); on the CPU, kernels in interpret mode
 test:
-	PYTHONPATH=$(PYTHONPATH) python -m pytest -x -q
+	PYTHONPATH=$(PYTHONPATH) JAX_PLATFORMS=cpu python -m pytest -x -q
 
 # ruff (pinned in requirements-dev.txt; config in ruff.toml)
 lint:

@@ -442,9 +442,9 @@ def paged_decode_bytes_per_device(arch: ArchConfig, shape: ShapeConfig, model,
     The dense decode model above streams the whole ``(B, max_len)`` cache
     allocation; the paged kernel instead walks each row's block-table
     entries and streams KV at **page granularity** — ``ceil(kv_len / P)``
-    pages per row per attention layer — plus the int32 block-table row and
-    per-slot position metadata the kernel prefetches, plus the one slot it
-    writes.  Weights and residual-stream activations match the dense
+    pages per row per attention layer — plus the int32 block-table row the
+    kernel prefetches (it masks by position, so no per-slot metadata is
+    read), plus the one slot it writes.  Weights and residual-stream activations match the dense
     model.  Returns ``None`` when the paged pool would not engage (no
     pageable KV: ssm/hybrid state, rolling-SWA slot reuse).  ``kv_elt`` is
     the arena element size — pass 1 for an int8 arena (the per-(page,
@@ -467,7 +467,6 @@ def paged_decode_bytes_per_device(arch: ArchConfig, shape: ShapeConfig, model,
     pages = -(-shape.seq_len // page_size)
     kv_read = 2 * B * pages * page_size * hkv * dh * kv_elt * n_attn
     meta = B * pages * 4 * n_attn                    # block-table row
-    meta += B * pages * page_size * 4 * n_attn       # slot_pos validity
     if kv_elt == 1:
         meta += 2 * B * pages * 4 * n_attn           # k/v per-page scales
     kv_write = 2 * B * hkv * dh * kv_elt * n_attn

@@ -12,7 +12,7 @@ import traceback
 from typing import List
 
 
-def main() -> None:
+def main() -> int:
     from benchmarks import (
         channels,
         elastic_sched,
@@ -22,11 +22,14 @@ def main() -> None:
     )
 
     rows: List[dict] = []
+    failed = []
     for mod in (tail_latency, isolation, elasticity, elastic_sched, channels):
         try:
             mod.run(rows)
         except Exception:
+            # keep measuring the other sections; the exit code reports it
             traceback.print_exc()
+            failed.append(mod.__name__)
             rows.append({
                 "name": f"{mod.__name__}/ERROR",
                 "us_per_call": -1,
@@ -37,7 +40,8 @@ def main() -> None:
     for r in rows:
         d = str(r["derived"]).replace(",", ";")
         print(f"{r['name']},{r['us_per_call']:.3f},{d}")
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

@@ -11,8 +11,12 @@ spec's desired ``ncols`` and re-applies it.  The reconciler turns every
 this file never touches a resize/transfer primitive.
 
 Run:  PYTHONPATH=src python examples/colocate_elastic.py
+(a CPU demo on 8 virtual host devices)
 """
 import os
+# a CPU virtual-device demo: eight host devices stand in for the 2x4
+# column grid, on any machine (a one-chip host has too few devices)
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import dataclasses
@@ -40,6 +44,11 @@ MAX_LEN, SLOTS, PROMPT_LEN, MAX_NEW = 48, 4, 12, 4
 
 
 def main():
+    if len(jax.devices()) < 8:
+        raise SystemExit(
+            f"{__file__} is a CPU virtual-device demo and needs 8 host "
+            f"devices; XLA_FLAGS={os.environ.get('XLA_FLAGS')!r} gave "
+            f"{len(jax.devices())}")
     grid = DeviceGrid.from_flat(jax.devices(), pods=1, rows=2, cols=4)
     sup = Supervisor(grid)
     arch = smoke_config(get_arch("qwen3-4b"))

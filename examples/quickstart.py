@@ -8,9 +8,12 @@ through ``Supervisor.apply`` — the reconciler turns the spec diff into
 create/resize/channel primitives.
 
 Run:  PYTHONPATH=src python examples/quickstart.py
-(uses 8 virtual host devices so resize/transfer are real)
+(a CPU demo: 8 virtual host devices, so resize/transfer are real)
 """
 import os
+# a CPU virtual-device demo: eight host devices stand in for the 2x4
+# column grid, on any machine (a one-chip host has too few devices)
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import numpy as np
@@ -26,6 +29,11 @@ from repro.train.optimizer import OptConfig
 
 def main():
     # -- supervisor boots first (paper: the firstly-booted instance)
+    if len(jax.devices()) < 8:
+        raise SystemExit(
+            f"{__file__} is a CPU virtual-device demo and needs 8 host "
+            f"devices; XLA_FLAGS={os.environ.get('XLA_FLAGS')!r} gave "
+            f"{len(jax.devices())}")
     grid = DeviceGrid.from_flat(jax.devices(), pods=1, rows=2, cols=4)
     sup = Supervisor(grid)
     print(f"supervisor up: grid={grid.shape}, epoch={sup.table.epoch}")

@@ -20,12 +20,15 @@ imperative demos sequenced by hand:
   requests lost, zero manual primitive calls.
 
 Run:  PYTHONPATH=src python examples/serve_disagg.py [--trace-out FILE]
-(uses 8 virtual host devices so the cells sit on disjoint zones;
+(a CPU demo: 8 virtual host devices, so the cells sit on disjoint zones;
 ``--trace-out`` exports the whole run — request span trees + the
 daemon's decision audit — as Chrome trace-event JSON, openable in
 Perfetto / chrome://tracing: ``make trace-demo``)
 """
 import os
+# a CPU virtual-device demo: eight host devices stand in for the 2x4
+# column grid, on any machine (a one-chip host has too few devices)
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import argparse
@@ -56,6 +59,11 @@ def main(argv=None):
                     help="export the run as Chrome trace-event JSON "
                          "(Perfetto-loadable), incl. the decision audit")
     args = ap.parse_args(argv)
+    if len(jax.devices()) < 8:
+        raise SystemExit(
+            f"{__file__} is a CPU virtual-device demo and needs 8 host "
+            f"devices; XLA_FLAGS={os.environ.get('XLA_FLAGS')!r} gave "
+            f"{len(jax.devices())}")
     grid = DeviceGrid.from_flat(jax.devices(), pods=1, rows=2, cols=4)
     sup = Supervisor(grid)
     arch = smoke_config(get_arch("qwen3-4b"))

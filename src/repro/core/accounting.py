@@ -63,17 +63,6 @@ def collective_bytes(hlo_text: str) -> Dict[str, int]:
     return dict(out)
 
 
-def _normalize_cost_analysis(ca) -> Dict[str, float]:
-    """``Compiled.cost_analysis()`` returns a dict on newer jax and a
-    per-device *list* of dicts on older releases (one entry per local
-    device, all identical under SPMD).  Normalize to one flat dict."""
-    if ca is None:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
-
-
 @dataclasses.dataclass
 class RequestMetrics:
     """Per-request serving latencies (seconds), attributed to one cell."""
@@ -153,7 +142,7 @@ class CellAccounting:
         self.recorder = FlightRecorder(cell_name)
 
     def register_program(self, name: str, compiled, hlo_text: Optional[str] = None):
-        ca = _normalize_cost_analysis(compiled.cost_analysis())
+        ca = compiled.cost_analysis() or {}
         ma = compiled.memory_analysis()
         text = hlo_text if hlo_text is not None else compiled.as_text()
         pc = ProgramCost(

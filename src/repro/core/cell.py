@@ -169,13 +169,18 @@ class Cell:
     # ------------------------------------------------------------------
     def init_serve(self, params=None, rng=None):
         assert self.role == "serve"
-        if params is None:
-            params = self.model.init(rng if rng is not None else jax.random.PRNGKey(0))
         shardings = jax.tree.map(
             lambda s: jax.sharding.NamedSharding(self.mesh, s),
             self.model.params_pspecs(),
         )
-        self.serve_params = jax.device_put(params, shardings)
+        if params is None:
+            # one jitted program draws every leaf in place, already in
+            # the model dtype and sharding — no host-side float32 copies
+            rng = rng if rng is not None else jax.random.PRNGKey(0)
+            self.serve_params = jax.jit(
+                self.model.init, out_shardings=shardings)(rng)
+        else:
+            self.serve_params = jax.device_put(params, shardings)
         self.status = "running"
         return self.serve_params
 

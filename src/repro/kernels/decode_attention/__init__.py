@@ -1,7 +1,7 @@
 """Flash-decode kernels: dense (slot-indexed) and native paged variants.
 
 The paged op consumes the serving block table directly — ``q (B, 1, Hq,
-Dh)`` against a ``(num_pages, page_size, L, Hkv, Dh)`` arena, a ``(B,
+Dh)`` against a ``(num_pages, L, Hkv, page_size, Dh)`` arena, a ``(B,
 n_logical)`` int32 block table (entries ``>= num_pages`` are unmapped
 sentinels), per-row ``kv_len`` and a scalar ``layer`` index — so no
 contiguous per-slot KV copy is ever materialized.  Optional ``k_scale``/

@@ -11,19 +11,22 @@ Two variants share that structure:
 
 * ``decode_attention_bhd`` — dense per-slot caches (B, S, Hkv, Dh).
 * ``paged_decode_attention_bhd`` — the NATIVE PAGED kernel.  The KV lives
-  in a physical page arena (num_pages, page_size, L, Hkv, Dh) shared by
+  in a physical page arena (num_pages, L, Hkv, page_size, Dh) shared by
   every request; each batch row's pages are named by a block-table row.
   The block table, per-row ``kv_len`` and the arena ``layer`` index ride
   scalar prefetch (``pltpu.PrefetchScalarGridSpec``), so the K/V
   BlockSpec index maps dereference ``block_table[b, j]`` and the kernel
   walks each row's physical pages DIRECTLY in the arena — no contiguous
-  per-slot KV copy is ever materialized (the "gather tax" of
-  serve/kvpool.py's dense fallback).  Sentinel entries (>= num_pages)
-  are clamped in the index map and fully masked in the body (their
-  ``slot_pos`` is ignored), so unmapped pages contribute nothing.
-  Per-slot absolute positions come from the arena's ``slot_pos`` plane,
-  which also masks partially filled pages.  Int8 arenas dequantize
-  in-kernel with a per-(page, layer) scale block.
+  per-slot KV copy is ever materialized.  Each grid step reads one
+  (page_size, Dh) tile of one (page, layer, KV head), which keeps every
+  block's trailing dims tile-legal for Mosaic.  Sentinel entries
+  (>= num_pages) are clamped in the index map and skipped in the body,
+  so unmapped pages contribute nothing.  Masking is by position: logical
+  page j holds positions [j*P, j*P + P), and the serving plane writes
+  every position below a row's ``kv_len`` before it is read (shared
+  prefix pages are full pages), so ``j*P + i < kv_len`` is exactly the
+  set of filled slots.  Int8 arenas dequantize in-kernel with
+  per-(page, layer) scales gathered through the block table into SMEM.
 
 On real hardware the page/nk dimension maps to the sequential grid walk
 (``arbitrary``), giving the classic split-KV streaming pattern; splits
@@ -39,8 +42,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels._compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -122,7 +123,7 @@ def decode_attention_bhd(
             pltpu.VMEM((G,), jnp.float32),
             pltpu.VMEM((G, Dh), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -132,15 +133,14 @@ def decode_attention_bhd(
 
 
 def _paged_decode_kernel(
-    bt_ref, kvlen_ref, layer_ref,        # scalar prefetch (SMEM)
-    q_ref, k_ref, v_ref, sp_ref, *rest,
-    scale: float, page: int, n_log: int, G: int, num_pages: int, quant: bool,
+    bt_ref, kvlen_ref, layer_ref, *refs,  # scalar prefetch (SMEM), then blocks
+    scale: float, page: int, n_log: int, num_pages: int, quant: bool,
 ):
     del layer_ref  # consumed by the BlockSpec index maps only
     if quant:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+        ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
     else:
-        o_ref, m_scr, l_scr, acc_scr = rest
+        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
     b = pl.program_id(0)
     j = pl.program_id(2)
 
@@ -158,25 +158,24 @@ def _paged_decode_kernel(
     @pl.when((page_id < num_pages) & (j * page < kv_len))
     def _body():
         q = q_ref[0, 0].astype(jnp.float32)                # (G, Dh)
-        k = k_ref[0, :, 0, 0].astype(jnp.float32)          # (P, Dh)
-        v = v_ref[0, :, 0, 0].astype(jnp.float32)
+        k = k_ref[0, 0, 0].astype(jnp.float32)             # (P, Dh)
+        v = v_ref[0, 0, 0].astype(jnp.float32)
         if quant:
-            k = k * ks_ref[0, 0]
-            v = v * vs_ref[0, 0]
+            k = k * ks_ref[b * n_log + j]
+            v = v * vs_ref[b * n_log + j]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale                                          # (G, P)
-        sp = sp_ref[0, :, 0]                               # (P,)
-        valid = (sp >= 0) & (sp < kv_len)
-        s = jnp.where(valid[None, :], s, NEG_INF)
+        pos = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < kv_len, s, NEG_INF)
 
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_scr[...]                                # (G, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=1)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
+        l_scr[...] = l_scr[...] * corr + p.sum(axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -185,65 +184,71 @@ def _paged_decode_kernel(
     @pl.when(j == n_log - 1)
     def _finish():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+
+
+def page_scales(scale, block_table, layer):
+    """Per-(row, logical page) int8 dequantization scales of one arena
+    layer, flattened for scalar prefetch: ``scale`` (N, L) gathered
+    through the block table (sentinels clamp; their pages never run)."""
+    N = scale.shape[0]
+    btc = jnp.minimum(block_table, N - 1)
+    return jnp.take(scale, layer, axis=1)[btc].reshape(-1).astype(jnp.float32)
 
 
 def paged_decode_attention_bhd(
-    q, k_arena, v_arena, slot_pos, block_table, kv_len, layer,
+    q, k_arena, v_arena, block_table, kv_len, layer,
     *, k_scale=None, v_scale=None, interpret: bool = True,
 ):
     """Paged flash-decode: q (B, Hq, Dh) vs a block-table-indirected arena.
 
-    k/v_arena: (N, P, L, Hkv, Dh); slot_pos: (N, P, L) int32 absolute
-    position per slot (-1 = empty); block_table: (B, n_log) int32, entries
-    >= N are unmapped sentinels; kv_len: (B,) valid count; layer: () int32
-    arena layer to read.  k/v_scale: (N, L) f32 per-(page, layer)
+    k/v_arena: (N, L, Hkv, P, Dh) — page-major, then layer and KV head, so
+    one (page, layer, head) block is a contiguous (P, Dh) tile;
+    block_table: (B, n_log) int32, entries >= N are unmapped sentinels;
+    kv_len: (B,) valid count (positions [0, kv_len) are attended); layer:
+    () int32 arena layer to read.  k/v_scale: (N, L) f32 per-(page, layer)
     dequantization scales for int8 arenas (None = float arena).
     Returns (B, Hq, Dh).
     """
     B, Hq, Dh = q.shape
-    N, P, _L, Hkv, _ = k_arena.shape
+    N, _L, Hkv, P, _ = k_arena.shape
     G = Hq // Hkv
     n_log = block_table.shape[1]
     qg = q.reshape(B, Hkv, G, Dh)
     bt_flat = block_table.reshape(-1).astype(jnp.int32)
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
     quant = k_scale is not None
-
-    def phys(b, j, bt):
-        return jnp.minimum(bt[b * n_log + j], N - 1)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, G, Dh), lambda b, h, j, bt, kvl, lyr: (b, h, 0, 0)),
-        pl.BlockSpec((1, P, 1, 1, Dh),
-                     lambda b, h, j, bt, kvl, lyr: (phys(b, j, bt), 0, lyr[0], h, 0)),
-        pl.BlockSpec((1, P, 1, 1, Dh),
-                     lambda b, h, j, bt, kvl, lyr: (phys(b, j, bt), 0, lyr[0], h, 0)),
-        pl.BlockSpec((1, P, 1),
-                     lambda b, h, j, bt, kvl, lyr: (phys(b, j, bt), 0, lyr[0])),
-    ]
-    args = [qg, k_arena, v_arena, slot_pos]
+    prefetch = [bt_flat, kv_len.astype(jnp.int32), layer_arr]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, 1), lambda b, h, j, bt, kvl, lyr: (phys(b, j, bt), lyr[0])),
-            pl.BlockSpec((1, 1), lambda b, h, j, bt, kvl, lyr: (phys(b, j, bt), lyr[0])),
-        ]
-        args += [k_scale, v_scale]
+        prefetch += [page_scales(k_scale, block_table, layer),
+                     page_scales(v_scale, block_table, layer)]
+
+    def kv_map(b, h, j, bt, kvl, lyr, *_):
+        # pages past the row's length repeat the last needed block, so
+        # the pipeline issues no DMA for them
+        jj = jnp.minimum(j, jnp.maximum(kvl[b] - 1, 0) // P)
+        return jnp.minimum(bt[b * n_log + jj], N - 1), lyr[0], h, 0, 0
+
+    def q_map(b, h, j, *_):
+        return b, h, 0, 0
 
     kernel = functools.partial(
         _paged_decode_kernel,
-        scale=1.0 / math.sqrt(Dh), page=P, n_log=n_log, G=G,
-        num_pages=N, quant=quant,
+        scale=1.0 / math.sqrt(Dh), page=P, n_log=n_log, num_pages=N,
+        quant=quant,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(prefetch),
         grid=(B, Hkv, n_log),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, Dh),
-                               lambda b, h, j, bt, kvl, lyr: (b, h, 0, 0)),
+        in_specs=[
+            pl.BlockSpec((1, 1, G, Dh), q_map),
+            pl.BlockSpec((1, 1, 1, P, Dh), kv_map),
+            pl.BlockSpec((1, 1, 1, P, Dh), kv_map),
+        ],
+        out_specs=pl.BlockSpec((1, 1, G, Dh), q_map),
         scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, Dh), jnp.float32),
         ],
     )
@@ -251,10 +256,10 @@ def paged_decode_attention_bhd(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dh), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="paged_decode_attention",
-    )(bt_flat, kv_len.astype(jnp.int32), layer_arr, *args)
+    )(*prefetch, qg, k_arena, v_arena)
     return out.reshape(B, Hq, Dh)

@@ -26,16 +26,16 @@ def decode_attention(q, k_cache, v_cache, kv_len, *, block_k: int = 512):
     return out[:, None]
 
 
-def paged_decode_attention(q, k_arena, v_arena, slot_pos, block_table,
-                           kv_len, layer, *, k_scale=None, v_scale=None):
+def paged_decode_attention(q, k_arena, v_arena, block_table, kv_len, layer,
+                           *, k_scale=None, v_scale=None):
     """q: (B, 1, Hq, Dh) vs a paged arena (see ``paged_decode_attention_bhd``).
 
     Unjitted on purpose — traced inside the caller's (model) jit so the
     arena is never copied across a jit boundary per layer.
     """
     out = paged_decode_attention_bhd(
-        q[:, 0], k_arena, v_arena, slot_pos, block_table,
-        kv_len.astype(jnp.int32), layer,
+        q[:, 0], k_arena, v_arena, block_table, kv_len.astype(jnp.int32),
+        layer,
         k_scale=k_scale, v_scale=v_scale, interpret=not _on_tpu(),
     )
     return out[:, None]

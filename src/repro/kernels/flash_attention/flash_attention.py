@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
+from repro.kernels.decode_attention.decode_attention import page_scales
 
 NEG_INF = -1e30
 
@@ -131,7 +131,7 @@ def flash_attention_bhsd(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, Dh), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -140,16 +140,15 @@ def flash_attention_bhsd(
 
 
 def _paged_extend_kernel(
-    bt_ref, pos_ref, layer_ref,          # scalar prefetch (SMEM)
-    q_ref, k_ref, v_ref, sp_ref, *rest,
+    bt_ref, pos_ref, layer_ref, *refs,  # scalar prefetch (SMEM), then blocks
     scale: float, block_q: int, page: int, n_log: int, num_pages: int,
     quant: bool,
 ):
     del layer_ref  # consumed by the BlockSpec index maps only
     if quant:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+        ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
     else:
-        o_ref, m_scr, l_scr, acc_scr = rest
+        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
     b = pl.program_id(0)
     qi = pl.program_id(2)
     j = pl.program_id(3)
@@ -163,32 +162,31 @@ def _paged_extend_kernel(
     page_id = bt_ref[b * n_log + j]
     # newest attendable position for this q block (absolute layout:
     # logical page j holds positions [j*P, j*P + P))
-    q_hi = pos_ref[b] + (qi + 1) * block_q - 1
+    q_lo = pos_ref[b] + qi * block_q
+    q_hi = q_lo + block_q - 1
 
     @pl.when((page_id < num_pages) & (j * page <= q_hi))
     def _body():
         q = q_ref[0, 0].astype(jnp.float32)                # (bq, Dh)
-        k = k_ref[0, :, 0, 0].astype(jnp.float32)          # (P, Dh)
-        v = v_ref[0, :, 0, 0].astype(jnp.float32)
+        k = k_ref[0, 0, 0].astype(jnp.float32)             # (P, Dh)
+        v = v_ref[0, 0, 0].astype(jnp.float32)
         if quant:
-            k = k * ks_ref[0, 0]
-            v = v * vs_ref[0, 0]
+            k = k * ks_ref[b * n_log + j]
+            v = v * vs_ref[b * n_log + j]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale                                          # (bq, P)
-        q_pos = pos_ref[b] + qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, page), 0)
-        sp = sp_ref[0, :, 0]                               # (P,)
-        valid = (sp[None, :] >= 0) & (sp[None, :] <= q_pos)
-        s = jnp.where(valid, s, NEG_INF)
+        q_pos = q_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        k_pos = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(k_pos <= q_pos, s, NEG_INF)
 
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_scr[...]                                # (bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=1)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
+        l_scr[...] = l_scr[...] * corr + p.sum(axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -197,11 +195,11 @@ def _paged_extend_kernel(
     @pl.when(j == n_log - 1)
     def _finish():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def paged_extend_attention_bhsd(
-    q, k_arena, v_arena, slot_pos, block_table, pos, layer,
+    q, k_arena, v_arena, block_table, pos, layer,
     *, k_scale=None, v_scale=None, block_q: int = 128,
     interpret: bool = True,
 ):
@@ -210,13 +208,14 @@ def paged_extend_attention_bhsd(
     The multi-query sibling of ``paged_decode_attention_bhd`` (see
     kernels/decode_attention): row b's queries sit at absolute positions
     ``pos[b] + i`` behind a prefix already resident in the block-table's
-    pages; a slot is attended iff its ``slot_pos`` is valid (>= 0) and
-    <= the query position.  k/v_arena: (N, P, L, Hkv, Dh); slot_pos:
-    (N, P, L); block_table: (B, n_log) int32 (>= N = unmapped); pos:
-    (B,) int32 per-row offsets; layer: () int32.  Returns (B, Hq, Sq, Dh).
+    pages; slot i of logical page j holds position ``j*P + i`` and is
+    attended iff that position is <= the query position.  k/v_arena:
+    (N, L, Hkv, P, Dh); block_table: (B, n_log) int32 (>= N = unmapped);
+    pos: (B,) int32 per-row offsets; layer: () int32.  Returns
+    (B, Hq, Sq, Dh).
     """
     B, Hq, Sq, Dh = q.shape
-    N, P, _L, Hkv, _ = k_arena.shape
+    N, _L, Hkv, P, _ = k_arena.shape
     G = Hq // Hkv
     n_log = block_table.shape[1]
     block_q = min(block_q, Sq)
@@ -225,27 +224,19 @@ def paged_extend_attention_bhsd(
     bt_flat = block_table.reshape(-1).astype(jnp.int32)
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
     quant = k_scale is not None
-
-    def phys(b, j, bt):
-        return jnp.minimum(bt[b * n_log + j], N - 1)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, block_q, Dh),
-                     lambda b, h, i, j, bt, ps, lyr: (b, h, i, 0)),
-        pl.BlockSpec((1, P, 1, 1, Dh),
-                     lambda b, h, i, j, bt, ps, lyr: (phys(b, j, bt), 0, lyr[0], h // G, 0)),
-        pl.BlockSpec((1, P, 1, 1, Dh),
-                     lambda b, h, i, j, bt, ps, lyr: (phys(b, j, bt), 0, lyr[0], h // G, 0)),
-        pl.BlockSpec((1, P, 1),
-                     lambda b, h, i, j, bt, ps, lyr: (phys(b, j, bt), 0, lyr[0])),
-    ]
-    args = [q, k_arena, v_arena, slot_pos]
+    prefetch = [bt_flat, pos.astype(jnp.int32), layer_arr]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, 1), lambda b, h, i, j, bt, ps, lyr: (phys(b, j, bt), lyr[0])),
-            pl.BlockSpec((1, 1), lambda b, h, i, j, bt, ps, lyr: (phys(b, j, bt), lyr[0])),
-        ]
-        args += [k_scale, v_scale]
+        prefetch += [page_scales(k_scale, block_table, layer),
+                     page_scales(v_scale, block_table, layer)]
+
+    def kv_map(b, h, i, j, bt, ps, lyr, *_):
+        # pages past this q block's newest position repeat the last
+        # needed block, so the pipeline issues no DMA for them
+        jj = jnp.minimum(j, (ps[b] + (i + 1) * block_q - 1) // P)
+        return jnp.minimum(bt[b * n_log + jj], N - 1), lyr[0], h // G, 0, 0
+
+    def q_map(b, h, i, j, *_):
+        return b, h, i, 0
 
     kernel = functools.partial(
         _paged_extend_kernel,
@@ -253,14 +244,17 @@ def paged_extend_attention_bhsd(
         num_pages=N, quant=quant,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(prefetch),
         grid=(B, Hq, nq, n_log),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, block_q, Dh),
-                               lambda b, h, i, j, bt, ps, lyr: (b, h, i, 0)),
+        in_specs=[
+            pl.BlockSpec((1, 1, block_q, Dh), q_map),
+            pl.BlockSpec((1, 1, 1, P, Dh), kv_map),
+            pl.BlockSpec((1, 1, 1, P, Dh), kv_map),
+        ],
+        out_specs=pl.BlockSpec((1, 1, block_q, Dh), q_map),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, Dh), jnp.float32),
         ],
     )
@@ -268,9 +262,9 @@ def paged_extend_attention_bhsd(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="paged_extend_attention",
-    )(bt_flat, pos.astype(jnp.int32), layer_arr, *args)
+    )(*prefetch, q, k_arena, v_arena)

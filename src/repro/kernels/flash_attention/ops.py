@@ -40,9 +40,8 @@ def flash_attention(
     return out.transpose(0, 2, 1, 3)
 
 
-def paged_extend_attention(q, k_arena, v_arena, slot_pos, block_table,
-                           pos, layer, *, k_scale=None, v_scale=None,
-                           block_q: int = 128):
+def paged_extend_attention(q, k_arena, v_arena, block_table, pos, layer,
+                           *, k_scale=None, v_scale=None, block_q: int = 128):
     """q: (B, S, Hq, Dh) vs a paged arena (see ``paged_extend_attention_bhsd``).
 
     Unjitted on purpose — traced inside the caller's (model) jit so the
@@ -52,8 +51,7 @@ def paged_extend_attention(q, k_arena, v_arena, slot_pos, block_table,
     S = q.shape[1]
     bq = S if S <= block_q else math.gcd(S, block_q)
     out = paged_extend_attention_bhsd(
-        q.transpose(0, 2, 1, 3), k_arena, v_arena, slot_pos, block_table,
-        pos, layer, k_scale=k_scale, v_scale=v_scale, block_q=bq,
-        interpret=not _on_tpu(),
+        q.transpose(0, 2, 1, 3), k_arena, v_arena, block_table, pos, layer,
+        k_scale=k_scale, v_scale=v_scale, block_q=bq, interpret=not _on_tpu(),
     )
     return out.transpose(0, 2, 1, 3)

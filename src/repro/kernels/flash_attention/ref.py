@@ -7,6 +7,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.decode_attention.ref import page_positions, paged_pages
+
 
 def attention_ref(
     q, k, v, *, causal: bool = True, window: Optional[int] = None,
@@ -36,40 +38,30 @@ def attention_ref(
 
 
 def paged_extend_attention_ref(
-    q, k_arena, v_arena, slot_pos, block_table, pos, layer,
+    q, k_arena, v_arena, block_table, pos, layer,
     *, k_scale=None, v_scale=None,
 ):
     """Pure-jnp oracle for the paged extend kernel (same signature).
 
-    q: (B, Hq, Sq, Dh); k/v_arena: (N, P, L, Hkv, Dh); slot_pos:
-    (N, P, L); block_table: (B, n_log) int32 (>= N unmapped); pos: (B,)
-    absolute offset of each row's first query; layer: () int32.  A slot
-    is attended iff its stored position is >= 0 and <= the query's
-    absolute position.  Returns (B, Hq, Sq, Dh).
+    q: (B, Hq, Sq, Dh); k/v_arena: (N, L, Hkv, P, Dh); block_table:
+    (B, n_log) int32 (>= N unmapped); pos: (B,) absolute offset of each
+    row's first query; layer: () int32.  Slot i of mapped logical page j
+    holds position j*P + i and is attended iff that position is <= the
+    query's absolute position.  Returns (B, Hq, Sq, Dh).
     """
     B, Hq, Sq, Dh = q.shape
-    N, P = k_arena.shape[0], k_arena.shape[1]
-    n_log = block_table.shape[1]
-    btc = jnp.minimum(block_table, N - 1)
-    k = jnp.take(k_arena, layer, axis=2)[btc]          # (B, n_log, P, Hkv, Dh)
-    v = jnp.take(v_arena, layer, axis=2)[btc]
-    sp = jnp.take(slot_pos, layer, axis=2)[btc]        # (B, n_log, P)
-    if k_scale is not None:
-        ks = jnp.take(k_scale, layer, axis=1)[btc]
-        vs = jnp.take(v_scale, layer, axis=1)[btc]
-        k = k.astype(jnp.float32) * ks[..., None, None, None]
-        v = v.astype(jnp.float32) * vs[..., None, None, None]
-    sp = jnp.where((block_table < N)[:, :, None], sp, -1)
-    Hkv = k.shape[3]
-    G = Hq // Hkv
-    k = jnp.repeat(k.reshape(B, n_log * P, Hkv, Dh), G, axis=2)
-    v = jnp.repeat(v.reshape(B, n_log * P, Hkv, Dh), G, axis=2)
-    sp = sp.reshape(B, n_log * P)
-    s = jnp.einsum("bhqd,bkhd->bhqk", q.astype(jnp.float32),
+    N, P = k_arena.shape[0], k_arena.shape[3]
+    k = paged_pages(k_arena, block_table, layer, k_scale)   # (B, Hkv, K, Dh)
+    v = paged_pages(v_arena, block_table, layer, v_scale)
+    kpos = page_positions(block_table, N, P)                # (B, K)
+    G = Hq // k.shape[1]
+    k = jnp.repeat(k, G, axis=1)
+    v = jnp.repeat(v, G, axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) / math.sqrt(Dh)
-    q_pos = pos[:, None] + jnp.arange(Sq)[None, :]     # (B, Sq)
-    valid = (sp[:, None, :] >= 0) & (sp[:, None, :] <= q_pos[:, :, None])
+    q_pos = pos[:, None] + jnp.arange(Sq)[None, :]          # (B, Sq)
+    valid = (kpos[:, None, :] >= 0) & (kpos[:, None, :] <= q_pos[:, :, None])
     s = jnp.where(valid[:, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bhqd", p, v.astype(jnp.float32))
+    out = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
     return out.astype(q.dtype)

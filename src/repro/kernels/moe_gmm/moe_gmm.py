@@ -17,9 +17,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (TPU lowering registration)
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
 
 
 def _gmm_kernel(cnt_ref, x_ref, w_ref, o_ref, *, block_c: int):
@@ -64,7 +63,7 @@ def gmm(x, w, counts, *, block_c: int = 128, block_f: int = 512,
         ],
         out_specs=pl.BlockSpec((1, block_c, block_f), lambda e, c, f: (e, c, f)),
         out_shape=jax.ShapeDtypeStruct((E, C, F), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
         interpret=interpret,

@@ -27,9 +27,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (TPU lowering registration)
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
 
 
 def _ssd_chunk_kernel(x_ref, dA_ref, b_ref, c_ref,
@@ -99,7 +98,7 @@ def ssd_chunks(x, dA, B, C, *, interpret: bool = True):
             jax.ShapeDtypeStruct((Bb, H, nc, P, N), jnp.float32),
             jax.ShapeDtypeStruct((Bb, H, nc), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
         interpret=interpret,

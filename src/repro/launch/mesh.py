@@ -10,20 +10,16 @@ from __future__ import annotations
 import jax
 
 
-def _axis_types_kwargs(n_axes: int) -> dict:
-    """``axis_types=`` only exists on newer jax (``jax.sharding.AxisType``);
-    older releases default every axis to Auto, so omitting the kwarg there
-    is equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _auto(n_axes: int) -> tuple:
+    """Auto (GSPMD-propagated) axes; ``jax.make_mesh`` defaults to
+    Explicit, which the sharding rules here do not use."""
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_mesh_for_devices(n_data: int, n_model: int, pods: int = 1):
@@ -31,10 +27,10 @@ def make_mesh_for_devices(n_data: int, n_model: int, pods: int = 1):
     if pods > 1:
         return jax.make_mesh(
             (pods, n_data, n_model), ("pod", "data", "model"),
-            **_axis_types_kwargs(3),
+            axis_types=_auto(3),
         )
     return jax.make_mesh(
-        (n_data, n_model), ("data", "model"), **_axis_types_kwargs(2)
+        (n_data, n_model), ("data", "model"), axis_types=_auto(2)
     )
 
 

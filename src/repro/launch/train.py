@@ -3,8 +3,8 @@
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-4b \
         --smoke --steps 50 [--ckpt-dir /tmp/ckpt] [--resume]
 
-``--smoke`` uses the reduced same-family config (CPU-friendly); the full
-configs are exercised via the dry-run.  The cell checkpoints periodically
+``--smoke`` uses the reduced same-family config (CPU-friendly); without it
+the published widths are trained.  The cell checkpoints periodically
 and ``--resume`` continues from the latest checkpoint (the data pipeline
 is step-deterministic, so restarts don't skew batches).
 """
@@ -20,6 +20,7 @@ from repro.configs.base import ShapeConfig, smoke_config, with_opt_level
 from repro.configs.registry import get_arch
 from repro.core import Supervisor, single_device_grid
 from repro.data.pipeline import DataConfig, SyntheticPipeline
+from repro.launch.compile_cache import use_compile_cache
 from repro.train.optimizer import OptConfig
 from repro.train.train_step import abstract_train_state, train_state_pspecs
 
@@ -27,7 +28,8 @@ from repro.train.train_step import abstract_train_state, train_state_pspecs
 def main(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="qwen3-4b")
-    p.add_argument("--smoke", action="store_true", default=True)
+    p.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                   default=False)
     p.add_argument("--steps", type=int, default=50)
     p.add_argument("--batch", type=int, default=8)
     p.add_argument("--seq", type=int, default=64)
@@ -38,6 +40,7 @@ def main(argv=None):
     p.add_argument("--compress-grads", action="store_true")
     args = p.parse_args(argv)
 
+    use_compile_cache()
     arch = get_arch(args.arch)
     if args.smoke:
         arch = smoke_config(arch)

@@ -82,14 +82,20 @@ def install_cross_memory(cache: Any, mem, slots: Sequence[int]) -> Any:
 # --------------------------------------------------------------------------
 # A *page* is ``page_size`` consecutive positions of ONE request's KV across
 # every positional cache leaf (all layers at once).  The canonical page
-# layout moves each KVSlice leaf's (batch, seq) axes to the front —
-# ``(num_pages, page_size, *rest)`` — so one integer page id addresses the
-# same positions in every leaf, whatever that leaf's stacking depth is
-# (layer-stacked dense caches, group-stacked hybrid shared KV, ...).  The
+# layout moves each KVSlice leaf's batch axis to the front and its
+# positions axis next to the innermost dim — k/v ``(num_pages, *stack,
+# Hkv, page_size, Dh)``, slot_pos ``(num_pages, *stack, page_size)`` — so
+# one integer page id addresses the same positions in every leaf, whatever
+# that leaf's stacking depth is (layer-stacked dense caches, group-stacked
+# hybrid shared KV, ...), and one (page, layer, KV head) is a contiguous
+# ``(page_size, Dh)`` tile: the block the paged Pallas kernels read.  The
 # block table maps ``(slot, logical_page) -> physical_page``; entries >=
 # ``num_pages`` are UNMAPPED sentinels: gathers fill (k/v = 0, slot_pos =
 # -1, i.e. position-masked) and scatters drop, so an unmapped page is
 # indistinguishable from an empty one and a write to it is a no-op.
+
+# canonical positions axis of each KVSlice field
+_POS_AXIS = KVSlice(k=-2, v=-2, slot_pos=-1)
 
 
 def _is_kv(x) -> bool:
@@ -161,88 +167,82 @@ def clear_kv_row(cache: Any, axes: list, row: int) -> Any:
     resident = strip_kv_nodes(cache)
     out_nodes = []
     for node, a in zip(nodes, axes):
-        sp = _to_canonical(node.slot_pos, a)
-        sp = sp.at[row].set(-1)
-        out_nodes.append(node._replace(slot_pos=_from_canonical(sp, a)))
+        sp = jnp.moveaxis(node.slot_pos, a, 0).at[row].set(-1)
+        out_nodes.append(node._replace(slot_pos=jnp.moveaxis(sp, 0, a)))
     return rebuild_kv_nodes(cache, resident, out_nodes)
 
 
-def _to_canonical(leaf: jnp.ndarray, axis: int) -> jnp.ndarray:
-    return jnp.moveaxis(leaf, (axis, axis + 1), (0, 1))
+def _to_canonical(node: KVSlice, axis: int) -> KVSlice:
+    """Dense KV node (batch at ``axis``, seq at ``axis + 1``) -> canonical
+    layout (batch first, positions at ``_POS_AXIS``)."""
+    return jax.tree.map(
+        lambda x, p: jnp.moveaxis(x, (axis, axis + 1), (0, p)),
+        node, _POS_AXIS)
 
 
-def _from_canonical(x: jnp.ndarray, axis: int) -> jnp.ndarray:
-    return jnp.moveaxis(x, (0, 1), (axis, axis + 1))
+def _from_canonical(node: KVSlice, axis: int) -> KVSlice:
+    return jax.tree.map(
+        lambda x, p: jnp.moveaxis(x, (0, p), (axis, axis + 1)),
+        node, _POS_AXIS)
+
+
+def _split_positions(x, p: int, page_size: int):
+    """Canonical array -> pages first: positions axis ``p`` (of length
+    n*P) splits into (n, P) and n moves to the front."""
+    i = x.ndim + p
+    x = x.reshape(x.shape[:i] + (x.shape[i] // page_size, page_size)
+                  + x.shape[i + 1:])
+    return jnp.moveaxis(x, i, 0)
+
+
+def _merge_pages(x, p: int):
+    """Inverse of :func:`_split_positions` (without the leading batch):
+    page stacks (n, ..., P at ``p``, ...) -> (..., n*P, ...)."""
+    x = jnp.moveaxis(x, 0, x.ndim + p - 1)
+    i = x.ndim + p - 1
+    return x.reshape(x.shape[:i] + (x.shape[i] * x.shape[i + 1],)
+                     + x.shape[i + 2:])
 
 
 def page_arena(model, num_pages: int, page_size: int) -> list:
-    """Physical page arena: one canonical ``(num_pages, page_size, *rest)``
-    KVSlice per positional cache node.  Built from ``init_cache`` so k/v
-    start zeroed and ``slot_pos`` starts -1 (every page empty)."""
+    """Physical page arena: one canonical page-layout KVSlice per
+    positional cache node.  Built from ``init_cache`` so k/v start zeroed
+    and ``slot_pos`` starts -1 (every page empty)."""
     full = model.init_cache(num_pages, page_size)
     axes = kv_node_axes(model, num_pages, page_size)
-    return [
-        KVSlice(k=_to_canonical(n.k, a), v=_to_canonical(n.v, a),
-                slot_pos=_to_canonical(n.slot_pos, a))
-        for n, a in zip(kv_cache_nodes(full), axes)
-    ]
+    return [_to_canonical(n, a) for n, a in zip(kv_cache_nodes(full), axes)]
 
 
 def gather_pages(arena: list, axes: list, block_table: jnp.ndarray,
                  page_size: int) -> list:
     """Materialize dense per-slot KV nodes from the arena through the
-    block table (jit-traceable; THE indirection in front of the existing
-    decode kernels).  ``block_table``: (B, n_logical) int32, entries >=
-    num_pages gather as empty (k/v 0, slot_pos -1)."""
-    B, n_log = block_table.shape
+    block table (jit-traceable).  ``block_table``: (B, n_logical) int32,
+    entries >= num_pages gather as empty (k/v 0, slot_pos -1)."""
     out = []
     for node, a in zip(arena, axes):
-        def g(x, fill):
+        def g(x, p, fill):
             y = jnp.take(x, block_table, axis=0, mode="fill",
-                         fill_value=fill)                 # (B, n_log, P, *rest)
-            y = y.reshape((B, n_log * page_size) + x.shape[2:])
-            return _from_canonical(y, a)
-        out.append(KVSlice(k=g(node.k, 0), v=g(node.v, 0),
-                           slot_pos=g(node.slot_pos, -1)))
-    return out
-
-
-def scatter_current_pages(arena: list, nodes: list, axes: list,
-                          block_table: jnp.ndarray, pos: jnp.ndarray,
-                          page_size: int) -> list:
-    """Write each slot's CURRENT page (the one holding position ``pos``)
-    from dense nodes back into the arena (jit-traceable).  Only the
-    current page can have changed during a decode step, and by the
-    copy-on-write invariant it is always a private page — shared
-    (interned) pages are never written.  Unmapped entries drop."""
-    B = pos.shape[0]
-    pg = pos // page_size                                  # (B,)
-    phys = jnp.take_along_axis(block_table, pg[:, None], axis=1)[:, 0]
-    out = []
-    for arena_node, node, a in zip(arena, nodes, axes):
-        def s(dst, leaf):
-            c = _to_canonical(leaf, a)                     # (B, S, *rest)
-            c = c.reshape((B, c.shape[1] // page_size, page_size) + c.shape[2:])
-            cur = c[jnp.arange(B), pg]                     # (B, P, *rest)
-            return dst.at[phys].set(cur, mode="drop")
-        out.append(KVSlice(k=s(arena_node.k, node.k),
-                           v=s(arena_node.v, node.v),
-                           slot_pos=s(arena_node.slot_pos, node.slot_pos)))
+                         fill_value=fill)              # (B, n_log, *page)
+            return jax.vmap(lambda r: _merge_pages(r, p))(y)
+        dense = KVSlice(k=g(node.k, -2, 0), v=g(node.v, -2, 0),
+                        slot_pos=g(node.slot_pos, -1, -1))
+        out.append(_from_canonical(dense, a))
     return out
 
 
 def extract_row_pages(cache: Any, axes: list, row: int, start_page: int,
                       n_pages: int, page_size: int) -> list:
-    """Slice ``n_pages`` canonical page stacks (one (n_pages, P, *rest)
-    array per k/v/slot_pos of each KV node) out of one row of a dense
-    cache — the page-granular payload of the prefill -> decode handoff."""
+    """Slice ``n_pages`` canonical page stacks (one (n_pages, ...) array
+    per k/v/slot_pos of each KV node) out of one row of a dense cache —
+    the page-granular payload of the prefill -> decode handoff."""
     out = []
     lo, hi = start_page * page_size, (start_page + n_pages) * page_size
     for node, a in zip(kv_cache_nodes(cache), axes):
-        def e(leaf):
-            x = _to_canonical(leaf, a)[row, lo:hi]
-            return x.reshape((n_pages, page_size) + x.shape[1:])
-        out.append(KVSlice(k=e(node.k), v=e(node.v), slot_pos=e(node.slot_pos)))
+        def e(x, p):
+            x = x[row]
+            x = jax.lax.slice_in_dim(x, lo, hi, axis=x.ndim + p)
+            return _split_positions(x, p, page_size)
+        out.append(jax.tree.map(e, _to_canonical(node, a), _POS_AXIS))
     return out
 
 
@@ -323,7 +323,7 @@ def quantize_page(x: jnp.ndarray, *, keep_axes=(0,)):
     """Symmetric int8 quantization with one scale per kept-axes index.
 
     ``keep_axes`` (sorted ascending) name the axes that keep their own
-    scale — e.g. ``(0, 2)`` on a canonical ``(n_pages, P, L, Hkv, Dh)``
+    scale — e.g. ``(0, 1)`` on a canonical ``(n_pages, L, Hkv, P, Dh)``
     page stack gives one scale per (page, layer).  Returns
     ``(q int8, scale f32)`` with ``scale.shape == tuple(x.shape[a] for a
     in keep_axes)``.  All-zero groups get scale 0 (dequantizes to 0).
@@ -350,20 +350,16 @@ def load_pages_into_row(cache: Any, template: Any, axes: list, row: int,
     resident context of an extend-prefill scratch row."""
     nodes = kv_cache_nodes(cache)
     resident = strip_kv_nodes(cache)
+    lo = start_page * page_size
     out_nodes = []
     for node, stack, a in zip(nodes, stacks, axes):
-        n_pages = stack.k.shape[0]
-        lo = start_page * page_size
-
-        def w(leaf, s):
-            x = _to_canonical(leaf, a)
-            flat = s.reshape((n_pages * page_size,) + s.shape[2:])
-            x = x.at[row, lo:lo + n_pages * page_size].set(
-                flat.astype(leaf.dtype))
-            return _from_canonical(x, a)
-
-        out_nodes.append(KVSlice(k=w(node.k, stack.k), v=w(node.v, stack.v),
-                                 slot_pos=w(node.slot_pos, stack.slot_pos)))
+        def w(x, s, p):
+            flat = _merge_pages(s, p).astype(x.dtype)
+            r = jax.lax.dynamic_update_slice_in_dim(
+                x[row], flat, lo, axis=flat.ndim + p)
+            return x.at[row].set(r)
+        out_nodes.append(_from_canonical(
+            jax.tree.map(w, _to_canonical(node, a), stack, _POS_AXIS), a))
     return rebuild_kv_nodes(template, resident, out_nodes)
 
 

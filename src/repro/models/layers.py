@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.kernels.decode_attention.ref import paged_pages
 from repro.models.param import PSpec  # noqa: F401  (re-exported for layer specs)
 
 F32 = jnp.float32
@@ -254,10 +255,12 @@ class PagedKVCache(NamedTuple):
     Precondition: absolute-position layout only — slot ``i`` of logical
     page ``j`` holds position ``j*P + i``.  The KVPool gate guarantees it
     (``sliding_window`` is None or >= max_len), so window masking never
-    binds and the paged kernels ignore it.
+    binds and the paged kernels ignore it.  The kernels mask by that
+    position (``slot_pos`` stays the arena's record of which slots were
+    written, for export, migration and the jnp page walk).
 
-    k/v: (N, P, L, Hkv, Dh) arena (float, or int8 with per-page scales);
-    slot_pos: (N, P, L) int32 absolute position per slot (-1 = empty);
+    k/v: (N, L, Hkv, P, Dh) arena (float, or int8 with per-page scales);
+    slot_pos: (N, L, P) int32 absolute position per slot (-1 = empty);
     block_table: (B, n_log) int32 physical page per logical page;
     layer: () int32 arena layer of this view;
     k_scale/v_scale: (N, L) f32 per-(page, layer) scales for int8 arenas.
@@ -271,33 +274,30 @@ class PagedKVCache(NamedTuple):
     v_scale: Optional[jnp.ndarray] = None
 
 
+def paged_kernels() -> bool:
+    """Paged attention runs the Pallas kernels on the TPU and the jnp
+    page walk (:func:`paged_gather` + dense refs) elsewhere."""
+    return jax.default_backend() == "tpu"
+
+
 def paged_gather(cache: "PagedKVCache"):
     """Walk a block table in pure jnp: (B, n_log*P) dense K/V/slot_pos view.
 
-    The interpret-mode half of the paged attention contract — identical
-    masking semantics to the Pallas kernels (sentinel pages contribute
-    slot_pos -1, i.e. masked zeros), and bit-identical inputs to the dense
-    refs, so CPU serving keeps token-identical output vs the dense path.
+    The off-TPU half of the paged attention contract (see
+    :func:`paged_kernels`): sentinel pages contribute slot_pos -1, i.e.
+    masked zeros, and the dense refs get bit-identical inputs, so CPU
+    serving keeps token-identical output vs the dense path.
     Int8 arenas are dequantized with their per-page scales on gather.
     """
-    N, P = cache.k.shape[0], cache.k.shape[1]
+    N = cache.k.shape[0]
     layer, bt = cache.layer, cache.block_table
-    B, n_log = bt.shape
     btc = jnp.minimum(bt, N - 1)                      # clamp sentinels
-    k_l = jnp.take(cache.k, layer, axis=2)            # (N, P, Hkv, Dh)
-    v_l = jnp.take(cache.v, layer, axis=2)
-    sp_l = jnp.take(cache.slot_pos, layer, axis=2)    # (N, P)
-    k_pg = k_l[btc]                                   # (B, n_log, P, Hkv, Dh)
-    v_pg = v_l[btc]
-    if cache.k_scale is not None:
-        ks = jnp.take(cache.k_scale, layer, axis=1)[btc]   # (B, n_log)
-        vs = jnp.take(cache.v_scale, layer, axis=1)[btc]
-        k_pg = k_pg.astype(F32) * ks[..., None, None, None]
-        v_pg = v_pg.astype(F32) * vs[..., None, None, None]
+    k = paged_pages(cache.k, bt, layer, cache.k_scale)    # (B, Hkv, S, Dh)
+    v = paged_pages(cache.v, bt, layer, cache.v_scale)
+    sp_l = jnp.take(cache.slot_pos, layer, axis=1)    # (N, P)
     sp = jnp.where((bt < N)[:, :, None], sp_l[btc], -1)
-    return (k_pg.reshape(B, n_log * P, *k_pg.shape[3:]),
-            v_pg.reshape(B, n_log * P, *v_pg.shape[3:]),
-            sp.reshape(B, n_log * P))
+    return (k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
+            sp.reshape(bt.shape[0], -1))
 
 
 def _quantize_to(arena_dtype, vals, scale):
@@ -313,7 +313,7 @@ def _paged_write_decode(cache: "PagedKVCache", k, v, pos):
     target pages drop the write.  Int8 arenas lazily initialize the
     per-page scale on first touch (scale 0 = untouched page).
     """
-    N, P = cache.k.shape[0], cache.k.shape[1]
+    N, P = cache.k.shape[0], cache.k.shape[3]
     layer, bt = cache.layer, cache.block_table
     phys = jnp.take_along_axis(bt, (pos // P)[:, None], axis=1)[:, 0]  # (B,)
     off = pos % P
@@ -328,9 +328,13 @@ def _paged_write_decode(cache: "PagedKVCache", k, v, pos):
         vs = vs.at[phys, layer].set(scv, mode="drop")
         k = _quantize_to(cache.k.dtype, k, sck)
         v = _quantize_to(cache.v.dtype, v, scv)
-    k_a = cache.k.at[phys, off, layer].set(k, mode="drop")
-    v_a = cache.v.at[phys, off, layer].set(v, mode="drop")
-    sp_a = cache.slot_pos.at[phys, off, layer].set(pos, mode="drop")
+    # one (row, head) Dh-vector per index: the scatter window stays the
+    # minor dim, so XLA keeps the arena in the layout the kernels read
+    h = jnp.arange(k.shape[1])[None, :]
+    at = (phys[:, None], layer, h, off[:, None])
+    k_a = cache.k.at[at].set(k, mode="drop")
+    v_a = cache.v.at[at].set(v, mode="drop")
+    sp_a = cache.slot_pos.at[phys, layer, off].set(pos, mode="drop")
     return cache._replace(k=k_a, v=v_a, slot_pos=sp_a, k_scale=ks, v_scale=vs)
 
 
@@ -341,7 +345,7 @@ def _paged_write_extend(cache: "PagedKVCache", k, v, positions):
     logical page is beyond the block-table width or unmapped drop the
     write.  Int8 scales use a scatter-max per target page.
     """
-    N, P = cache.k.shape[0], cache.k.shape[1]
+    N, P = cache.k.shape[0], cache.k.shape[3]
     layer, bt = cache.layer, cache.block_table
     n_log = bt.shape[1]
     lp = positions // P
@@ -360,9 +364,11 @@ def _paged_write_extend(cache: "PagedKVCache", k, v, positions):
         vs = vs.at[phys, layer].max(amax_v / 127.0, mode="drop")
         k = _quantize_to(cache.k.dtype, k, ks[physc, layer])
         v = _quantize_to(cache.v.dtype, v, vs[physc, layer])
-    k_a = cache.k.at[phys, off, layer].set(k, mode="drop")
-    v_a = cache.v.at[phys, off, layer].set(v, mode="drop")
-    sp_a = cache.slot_pos.at[phys, off, layer].set(positions, mode="drop")
+    h = jnp.arange(k.shape[2])[None, None, :]
+    at = (phys[..., None], layer, h, off[..., None])    # see _paged_write_decode
+    k_a = cache.k.at[at].set(k, mode="drop")
+    v_a = cache.v.at[at].set(v, mode="drop")
+    sp_a = cache.slot_pos.at[phys, layer, off].set(positions, mode="drop")
     return cache._replace(k=k_a, v=v_a, slot_pos=sp_a, k_scale=ks, v_scale=vs)
 
 
@@ -493,13 +499,13 @@ def attention_block(
             # Native paged suffix extension: write straight into the
             # arena's physical pages, attend via the block table.
             new_cache = _paged_write_extend(cache, k, v, positions)
-            if jax.default_backend() == "tpu":
+            if paged_kernels():
                 from repro.kernels.flash_attention.ops import (
                     paged_extend_attention,
                 )
                 out = paged_extend_attention(
-                    q, new_cache.k, new_cache.v, new_cache.slot_pos,
-                    new_cache.block_table, pos, new_cache.layer,
+                    q, new_cache.k, new_cache.v, new_cache.block_table, pos,
+                    new_cache.layer,
                     k_scale=new_cache.k_scale, v_scale=new_cache.v_scale,
                 )
             else:
@@ -523,13 +529,13 @@ def attention_block(
             # gather/scatter around the step).  Sharded decode does not
             # apply — the arena is replicated, rows are block-table rows.
             new_cache = _paged_write_decode(cache, k[:, 0], v[:, 0], pos)
-            if jax.default_backend() == "tpu":
+            if paged_kernels():
                 from repro.kernels.decode_attention.ops import (
                     paged_decode_attention,
                 )
                 out = paged_decode_attention(
-                    q, new_cache.k, new_cache.v, new_cache.slot_pos,
-                    new_cache.block_table, pos + 1, new_cache.layer,
+                    q, new_cache.k, new_cache.v, new_cache.block_table,
+                    pos + 1, new_cache.layer,
                     k_scale=new_cache.k_scale, v_scale=new_cache.v_scale,
                 )
             else:

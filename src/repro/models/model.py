@@ -89,7 +89,7 @@ def _scan_stack(fn, x, stacked, cache, *, remat: bool, policy: str,
     )
     if paged_nodes:
         # Paged KV rides the scan CARRY, not the xs: arena leaves have no
-        # layer-stacked leading dim (the whole (N, P, L, ...) arena flows
+        # layer-stacked leading dim (the whole (N, L, ...) arena flows
         # through every step), so slicing them per layer is impossible.
         # Instead the per-step xs carry only the layer index; the body
         # rebinds each PagedKVCache's ``layer`` field and threads the

@@ -184,8 +184,7 @@ def moe_block(p, x, cfg: ArchConfig, ctx: ShardCtx, *, train: bool) -> Tuple[jnp
         P(batch_spec[0], ctx.model_axis) if scattered
         else P(batch_spec[0], None)
     )
-    from repro.sharding.rules import shard_map_compat
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         wrapped,
         mesh=ctx.mesh,
         in_specs=(

@@ -113,8 +113,7 @@ def sharded_decode_attention(
     q_spec = ctx.pspec(("batch", None, None, None), q.shape)
     nk_spec = ctx.pspec(("batch", None, None, None), new_k.shape)
     pos_spec = ctx.pspec(("batch",), pos.shape)
-    from repro.sharding.rules import shard_map_compat
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local_fn,
         mesh=ctx.mesh,
         in_specs=(q_spec, kv_spec, kv_spec, sp_spec, nk_spec, nk_spec, pos_spec),

@@ -86,7 +86,7 @@ convention (``build_paged_serve_step`` / ``build_paged_extend_step``):
 the step function takes ``(params, arena, scales, resident, block_table,
 batch, rng)``; ``cache_utils.paged_view`` wraps each positional arena
 node in a :class:`~repro.models.layers.PagedKVCache` carrying the whole
-``(num_pages, page_size, L, Hkv, Dh)`` arena plus the batch's
+``(num_pages, L, Hkv, page_size, Dh)`` arena plus the batch's
 ``(B, n_logical)`` block table, and ``Model.decode`` /
 ``Model.prefill_extend`` thread that view into every attention layer
 (the arena rides the layer-scan carry; each step rebinds the ``layer``
@@ -96,9 +96,8 @@ kernels (``kernels/decode_attention``, ``kernels/flash_attention``) walk
 each row's pages directly in the arena via scalar-prefetched block-table
 index maps; on CPU an equivalent jnp page gather feeds the dense
 reference attention, bit-identical to the pre-paged path.  No contiguous
-per-slot KV copy is ever materialized in steady state:
-``gather_pages``/``scatter_current_pages`` survive only on the
-export/import/migration and cold-install paths.  With
+per-slot KV copy is ever materialized in steady state: only the
+export/import/migration and cold-install paths move whole pages.  With
 ``kv_dtype="int8"`` the arena stores int8 pages with per-(page, layer)
 scales — quantize on page write, dequantize in-kernel — doubling pool
 capacity at documented (small, non-exact) accuracy cost.
@@ -344,8 +343,8 @@ def _write_pages_q(arena: list, scales: list, page_ids, stacks: list):
     idx = jnp.asarray(page_ids, jnp.int32)
     new_arena, new_scales = [], []
     for a, (ks, vs), s in zip(arena, scales, stacks):
-        kq, ksc = quantize_page(s.k, keep_axes=(0, 2))
-        vq, vsc = quantize_page(s.v, keep_axes=(0, 2))
+        kq, ksc = quantize_page(s.k, keep_axes=(0, 1))
+        vq, vsc = quantize_page(s.v, keep_axes=(0, 1))
         new_arena.append(KVSlice(
             k=a.k.at[idx].set(kq), v=a.v.at[idx].set(vq),
             slot_pos=a.slot_pos.at[idx].set(s.slot_pos)))
@@ -439,8 +438,8 @@ class KVPool:
                                       slot_pos=a.slot_pos)
                               for a in self.arena]
                 self.kv_scales = [
-                    (jnp.zeros((self.num_pages, a.k.shape[2]), jnp.float32),
-                     jnp.zeros((self.num_pages, a.k.shape[2]), jnp.float32))
+                    (jnp.zeros((self.num_pages, a.k.shape[1]), jnp.float32),
+                     jnp.zeros((self.num_pages, a.k.shape[1]), jnp.float32))
                     for a in self.arena]
             else:
                 raise ValueError(f"unsupported kv_dtype {kv_dtype!r}")
@@ -1174,8 +1173,8 @@ class KVPool:
         if self.kv_scales is None:
             return stacks
         idx = jnp.asarray(page_ids, jnp.int32)
-        return [KVSlice(k=dequantize_page(s.k, ks[idx], keep_axes=(0, 2)),
-                        v=dequantize_page(s.v, vs[idx], keep_axes=(0, 2)),
+        return [KVSlice(k=dequantize_page(s.k, ks[idx], keep_axes=(0, 1)),
+                        v=dequantize_page(s.v, vs[idx], keep_axes=(0, 1)),
                         slot_pos=s.slot_pos)
                 for s, (ks, vs) in zip(stacks, self.kv_scales)]
 

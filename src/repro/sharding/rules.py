@@ -167,27 +167,6 @@ class ShardCtx:
         return frozenset(a for a in self.all_axes)
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, axis_names,
-                     check_vma: bool = False):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map(..., axis_names=, check_vma=)``; older
-    releases only have ``jax.experimental.shard_map.shard_map`` where manual
-    axes are the complement of ``auto`` and the flag is ``check_rep``.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=axis_names, check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(
-        f, mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma, auto=auto,
-    )
-
-
 def single_device_ctx() -> ShardCtx:
     """A trivial ctx for single-device tests (same code paths)."""
     dev = np.array(jax.devices()[:1]).reshape(1, 1)

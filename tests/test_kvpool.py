@@ -179,6 +179,35 @@ def test_prefix_hit_exact_colocated(arch):
 
 
 @pytest.mark.parametrize("arch", FAMILY_ARCHS)
+def test_paged_kernels_serve_like_page_walk(arch, monkeypatch):
+    """Serving through the Pallas paged kernels (interpret mode here, the
+    path the chip takes) emits the same tokens as the jnp page walk, cold
+    and warm: the kernels' position mask and the arena's ``slot_pos``
+    agree on every page the serving plane maps, shared prefixes
+    included."""
+    import repro.models.layers as layers
+    model, params = _model(arch)
+    cfg = model.cfg
+
+    def run():
+        bat = ContinuousBatcher(model, params, batch_slots=2,
+                                max_len=MAX_LEN, prefill_chunk=CHUNK,
+                                page_size=PAGE)
+        for r in _requests(cfg, [3, 5], shared=18):
+            bat.submit(r)
+        bat.run_until_drained()
+        for r in _requests(cfg, [4, 7], shared=18, seed=5, rid0=10):
+            bat.submit(r)
+        out = {r.rid: r.output for r in bat.run_until_drained()}
+        assert bat.pool.prefix_hit_tokens > 0
+        return out
+
+    ref = run()
+    monkeypatch.setattr(layers, "paged_kernels", lambda: True)
+    assert run() == ref, arch
+
+
+@pytest.mark.parametrize("arch", FAMILY_ARCHS)
 def test_paged_matches_legacy_dense_cache(arch):
     """The paged cache plane (block-table indirection + paged installs)
     serves the same outputs as the legacy dense per-slot cache on a cold

@@ -1,10 +1,10 @@
 """Regression pins for the seed-suite failure clusters.
 
-Each test pins one of the version-compat / correctness bugs fixed alongside
-the disaggregated-serving PR so they cannot silently reappear:
-  * Pallas TPU compiler-params rename (CompilerParams vs TPUCompilerParams)
-  * ``cost_analysis()`` returning a per-device list on older jax
-  * ``jax.sharding.AxisType`` absent on older jax (mesh construction)
+Each test pins one of the API / correctness bugs fixed alongside the
+disaggregated-serving PR so they cannot silently reappear:
+  * the kernels' Pallas TPU compiler params (``pltpu.CompilerParams``)
+  * ``cost_analysis()`` read as one dict into the cell's accounting
+  * meshes built with Auto axes (``jax.make_mesh`` defaults to Explicit)
   * ``ArrayChannel.map`` silently allowing disjoint-device zero-copy
 """
 import numpy as np
@@ -15,18 +15,23 @@ import jax.numpy as jnp
 
 
 def test_tpu_compiler_params_resolves_on_this_jax():
+    import importlib
+    import inspect
+
     from jax.experimental.pallas import tpu as pltpu
 
-    from repro.kernels._compat import tpu_compiler_params
-
-    cp = tpu_compiler_params(dimension_semantics=("parallel", "arbitrary"))
-    expected = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    assert isinstance(cp, expected)
+    cp = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
+    assert cp.dimension_semantics == ("parallel", "arbitrary")
+    for name in ("decode_attention.decode_attention",
+                 "flash_attention.flash_attention", "moe_gmm.moe_gmm",
+                 "ssd_scan.ssd_scan"):
+        mod = importlib.import_module(f"repro.kernels.{name}")
+        assert "pltpu.CompilerParams(" in inspect.getsource(mod)
 
 
 def test_kernels_run_under_interpret_mode():
-    """The four kernels construct their compiler params through the shim;
-    one representative call proves the pallas_call wiring still works."""
+    """The kernels construct ``pltpu.CompilerParams`` directly; one
+    representative call proves the pallas_call wiring still works."""
     from repro.kernels.flash_attention import flash_attention
 
     q = jnp.zeros((1, 8, 1, 8), jnp.float32)      # (B, S, H, Dh)
@@ -35,16 +40,16 @@ def test_kernels_run_under_interpret_mode():
 
 
 def test_cost_analysis_list_and_dict_normalized():
-    from repro.core.accounting import CellAccounting, _normalize_cost_analysis
+    from repro.core.accounting import CellAccounting
 
-    assert _normalize_cost_analysis(None) == {}
-    assert _normalize_cost_analysis([]) == {}
-    assert _normalize_cost_analysis({"flops": 5.0}) == {"flops": 5.0}
-    assert _normalize_cost_analysis([{"flops": 5.0}]) == {"flops": 5.0}
+    compiled = jax.jit(lambda x: x @ x).lower(jnp.ones((8, 8))).compile()
+    assert isinstance(compiled.cost_analysis(), dict)
+    pc = CellAccounting("c").register_program("real", compiled)
+    assert pc.flops_per_device > 0
 
     class FakeCompiled:
         def cost_analysis(self):
-            return [{"flops": 7.0, "bytes accessed": 3.0}]   # per-device list
+            return {"flops": 7.0, "bytes accessed": 3.0}
 
         def memory_analysis(self):
             return None
@@ -78,17 +83,14 @@ def test_cell_accounting_is_exact_after_training():
 
 
 def test_mesh_helpers_work_without_axis_type():
-    """mesh.py must construct meshes whether or not jax.sharding.AxisType
-    exists (it is absent on jax 0.4.x)."""
-    from repro.launch.mesh import _axis_types_kwargs, make_mesh_for_devices
+    """mesh.py builds Auto-axis meshes: ``jax.make_mesh`` would otherwise
+    default to Explicit axes, which the logical sharding rules do not
+    use."""
+    from repro.launch.mesh import make_mesh_for_devices
 
-    kw = _axis_types_kwargs(2)
-    if hasattr(jax.sharding, "AxisType"):
-        assert kw == {"axis_types": (jax.sharding.AxisType.Auto,) * 2}
-    else:
-        assert kw == {}
     mesh = make_mesh_for_devices(1, 1)
     assert mesh.axis_names == ("data", "model")
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,) * 2
 
 
 def test_channel_map_requires_shared_devices():
