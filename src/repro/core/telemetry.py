@@ -45,11 +45,14 @@ id), ``ph="X"`` complete events with microsecond ``ts``/``dur``.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import math
 import time
 from collections import deque
-from typing import Callable, Dict, Iterable, List, Optional
+from typing import Callable, Dict, Iterable, Iterator, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class TraceContext:
@@ -145,6 +148,18 @@ class _NullSpan:
 
 
 NULL_SPAN = _NullSpan()
+
+
+class Phase:
+    """What :meth:`FlightRecorder.phase` yields: the phase's start on the
+    recorder's clock and, once it has closed, its duration (both 0.0 on
+    a disabled recorder)."""
+
+    __slots__ = ("ts", "dur")
+
+    def __init__(self):
+        self.ts = 0.0
+        self.dur = 0.0
 
 
 class EventLog:
@@ -290,6 +305,9 @@ class FlightRecorder:
     * ``enabled=False`` turns every operation into a no-op returning
       :data:`NULL_SPAN`; the overhead gate in
       ``benchmarks/disagg_serving.py`` measures exactly this toggle.
+    * ``wait_s`` sums the seconds spent in ``wait`` phases (the host
+      blocked on the device) over the recorder's life; a caller reads
+      the difference across an interval.
     """
 
     def __init__(self, cell: str, *, clock: Callable[[], float] = time.monotonic,
@@ -301,8 +319,31 @@ class FlightRecorder:
         self.hists: Dict[str, HistogramSketch] = {}
         self._open: Dict[str, Span] = {}
         self._n = 0
+        self.wait_s = 0.0
 
     # -- spans ---------------------------------------------------------
+
+    @contextlib.contextmanager
+    def phase(self, name: str, wait: bool = False) -> Iterator[Phase]:
+        """A named phase of the serving loop.
+
+        It is a ``jax.profiler.TraceAnnotation``, so it lands on the
+        profiler's host line on the device trace's own clock (all but
+        free when no profiler runs), and on an enabled recorder it is
+        timed on the recorder's clock.  The phase adds nothing to the
+        ring: a site that keeps it calls :meth:`add_complete` with the
+        yielded :class:`Phase`'s times.  ``wait=True`` marks the host
+        blocked on the device; its seconds add to :attr:`wait_s`."""
+        ph = Phase()
+        with TraceAnnotation(name):
+            if not self.enabled:
+                yield ph
+                return
+            ph.ts = self.clock()
+            yield ph
+            ph.dur = max(self.clock() - ph.ts, 0.0)
+        if wait:
+            self.wait_s += ph.dur
 
     def start_span(self, name: str, trace_id=None,
                    parent: Optional[TraceContext] = None,

@@ -286,15 +286,15 @@ class ContinuousBatcher:
         from repro.serve.serve_step import run_prefill_group
         B = len(group)
         reqs = [r for _, r, _ in group]
-        t0 = self.rec.clock()
-        toks, rows_cache, self._rng, b_pad = run_prefill_group(
-            self._prefill, self.params, self._scratch, reqs,
-            chunk=self.prefill_chunk, max_len=self.max_len, rng=self._rng,
-            model=self.model, accounting=self.accounting,
-        )
-        t1 = self.rec.clock()
-        span_group(self.rec, "prefill", reqs, t0, t1, kind="cold", batch=B)
-        self.rec.record("prefill_s", t1 - t0)
+        with self.rec.phase("prefill:cold") as ph:
+            toks, rows_cache, self._rng, b_pad = run_prefill_group(
+                self._prefill, self.params, self._scratch, reqs,
+                chunk=self.prefill_chunk, max_len=self.max_len,
+                rng=self._rng, model=self.model, accounting=self.accounting,
+            )
+        span_group(self.rec, "prefill", reqs, ph.ts, ph.ts + ph.dur,
+                   kind="cold", batch=B)
+        self.rec.record("prefill_s", ph.dur)
         ckpts = None
         if self._snapshot:
             rows_cache, ckpts = rows_cache
@@ -340,18 +340,17 @@ class ContinuousBatcher:
         for slot, req in zip(slots, reqs):
             self.pool.map_suffix_pages(slot, len(req.prompt))
         bt_rows = np.asarray(self.pool.block_table[slots], np.int32)
-        t0 = self.rec.clock()
-        toks, resident_rows, self._rng, _b_pad = run_extend_group(
-            self._extend, self.params, self._scratch, self.pool, reqs,
-            leases, bt_rows, chunk=self.prefill_chunk,
-            max_len=self.max_len, rng=self._rng, model=self.model,
-            accounting=self.accounting,
-        )
-        t1 = self.rec.clock()
-        span_group(self.rec, "prefill", reqs, t0, t1, kind="warm",
-                   batch=len(group),
+        with self.rec.phase("prefill:warm") as ph:
+            toks, resident_rows, self._rng, _b_pad = run_extend_group(
+                self._extend, self.params, self._scratch, self.pool, reqs,
+                leases, bt_rows, chunk=self.prefill_chunk,
+                max_len=self.max_len, rng=self._rng, model=self.model,
+                accounting=self.accounting,
+            )
+        span_group(self.rec, "prefill", reqs, ph.ts, ph.ts + ph.dur,
+                   kind="warm", batch=len(group),
                    hit_tokens=sum(le.tokens for le in leases))
-        self.rec.record("prefill_s", t1 - t0)
+        self.rec.record("prefill_s", ph.dur)
         self.prefill_invocations += 1
         self.prefill_batch_sizes.append(len(group))
         for slot, req in zip(slots, reqs):
@@ -428,15 +427,15 @@ class ContinuousBatcher:
             "length": jnp.asarray(length),
         }
         self._rng, sub = jax.random.split(self._rng)
-        t0 = self.rec.clock()
-        toks, _logits, self.cache = self._extend(self.params, self.cache,
-                                                 batch, sub)
-        toks = np.asarray(toks)
-        t1 = self.rec.clock()
-        span_group(self.rec, "prefill", [r for _, r, _ in group], t0, t1,
-                   kind="warm_snapshot", batch=len(group),
+        with self.rec.phase("prefill:warm_snapshot") as ph:
+            toks, _logits, self.cache = self._extend(self.params, self.cache,
+                                                     batch, sub)
+            with self.rec.phase("prefill.wait", wait=True):
+                toks = np.asarray(toks)
+        span_group(self.rec, "prefill", [r for _, r, _ in group], ph.ts,
+                   ph.ts + ph.dur, kind="warm_snapshot", batch=len(group),
                    hit_tokens=sum(le.tokens for _, _, le in group))
-        self.rec.record("prefill_s", t1 - t0)
+        self.rec.record("prefill_s", ph.dur)
         self.prefill_invocations += 1
         self.prefill_batch_sizes.append(len(group))
         self._post_install([s for s, _, _ in group],
@@ -728,6 +727,7 @@ class ContinuousBatcher:
         free = self.free_slots()
         staged: List[tuple] = []        # chunked-eligible (slot, req, lease)
         taken = [0]                     # free-slot cursor
+        blocked = [0]                   # attempts refused by the pool
 
         def try_admit(req: Request) -> bool:
             # the scheduler's resource gate: bind the next free slot and
@@ -752,6 +752,7 @@ class ContinuousBatcher:
                                     tenant=getattr(req, "tenant", None))
                 except PoolExhausted:
                     self.pool.release_lease(lease)
+                    blocked[0] += 1
                     return False
             taken[0] += 1
             req.started_at = req.started_at or time.monotonic()
@@ -789,14 +790,45 @@ class ContinuousBatcher:
                 self._extend_group(group)
             else:
                 self._restore_group(group)
+        return taken[0], blocked[0]
 
     # -- one decode step over all busy slots -----------------------------
     def step(self) -> int:
-        self._admit()
-        busy = [s for s in range(self.B) if self.slot_req[s] is not None]
-        if not busy:
-            return 0
-        t0 = self.rec.clock() if self.rec.enabled else 0.0
+        """Admit what fits, then one decode step over the busy slots.
+
+        Profiler annotations (phases) nest as ``step`` > ``admit`` >
+        ``prefill:cold|warm`` > ``prefill.wait``, then ``step`` >
+        ``decode_step`` > ``decode.prep``, ``decode.wait``, then
+        ``step`` > ``decode.post``.  The recorder's ring gets one
+        ``step`` span per call that found work, with ``busy`` (rows
+        decoded), ``admitted``, ``blocked`` (admissions the pool
+        refused) and ``wait_s`` (seconds in the wait phases)."""
+        rec = self.rec
+        wait0 = rec.wait_s
+        with rec.phase("step") as ph:
+            with rec.phase("admit"):
+                admitted, blocked = self._admit()
+            busy = [s for s in range(self.B) if self.slot_req[s] is not None]
+            if busy:
+                with rec.phase("decode_step") as dec:
+                    with rec.phase("decode.prep"):
+                        toks = self._dispatch_decode(busy)
+                    with rec.phase("decode.wait", wait=True):
+                        toks = np.asarray(toks)   # device step complete
+                rec.add_complete("decode_step", dec.ts, dec.dur,
+                                 busy=len(busy))
+                rec.record("decode_step_s", dec.dur)
+                with rec.phase("decode.post"):
+                    self._advance_slots(busy, toks)
+        if busy or blocked:
+            rec.add_complete("step", ph.ts, ph.dur, busy=len(busy),
+                             admitted=admitted, blocked=blocked,
+                             wait_s=rec.wait_s - wait0)
+        return len(busy)
+
+    def _dispatch_decode(self, busy: List[int]):
+        """Upload the step's inputs and dispatch the jitted decode step;
+        returns its tokens, still on the device."""
         batch = {
             "tokens": jnp.asarray(self.cur_tok[:, None]),
             "pos": jnp.asarray(self.pos),
@@ -825,12 +857,11 @@ class ContinuousBatcher:
             toks, _logits, self.cache = self._step(self.params, self.cache,
                                                    batch, sub)
         self.decode_invocations += 1
-        toks = np.asarray(toks)       # sync point: device step complete
-        if self.rec.enabled:
-            t1 = self.rec.clock()
-            self.rec.add_complete("decode_step", t0, t1 - t0,
-                                  busy=len(busy))
-            self.rec.record("decode_step_s", t1 - t0)
+        return toks
+
+    def _advance_slots(self, busy: List[int], toks: np.ndarray):
+        """Hand each busy slot its token (or its next prompt token) and
+        finish the requests that are done."""
         now = time.monotonic()
         for s in busy:
             req = self.slot_req[s]
@@ -858,7 +889,6 @@ class ContinuousBatcher:
             )
             if finished:
                 self._finish(req, now, slot=s)
-        return len(busy)
 
     def drop_slot(self, slot: int) -> Optional[Request]:
         """Evict a slot's request WITHOUT finishing it (detach/requeue

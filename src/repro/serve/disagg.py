@@ -153,16 +153,15 @@ class PrefillWorker:
             build_snapshot_payloads,
             request_ctx_key,
         )
-        t0 = self.rec.clock()
-        toks, cache, self._rng, _b_pad = run_prefill_group(
-            self._step, self.cell.serve_params, self._scratch, group,
-            chunk=self.chunk, max_len=self.max_len, rng=self._rng,
-            model=self.model, accounting=self.cell.accounting,
-        )
-        t1 = self.rec.clock()
-        span_group(self.rec, "prefill", group, t0, t1, kind="cold",
-                   batch=len(group))
-        self.rec.record("prefill_s", t1 - t0)
+        with self.rec.phase("prefill:cold") as ph:
+            toks, cache, self._rng, _b_pad = run_prefill_group(
+                self._step, self.cell.serve_params, self._scratch, group,
+                chunk=self.chunk, max_len=self.max_len, rng=self._rng,
+                model=self.model, accounting=self.cell.accounting,
+            )
+        span_group(self.rec, "prefill", group, ph.ts, ph.ts + ph.dur,
+                   kind="cold", batch=len(group))
+        self.rec.record("prefill_s", ph.dur)
         ckpts = None
         if self._snapshot:
             cache, ckpts = cache
@@ -374,18 +373,17 @@ class PrefillWorker:
                     bt_rows[i, lp] = node.page
                 for j, pg in enumerate(temps[i]):
                     bt_rows[i, lease.pages + j] = pg
-            t0 = self.rec.clock()
-            toks, rows, self._rng, _b_pad = run_extend_group(
-                self._extend, self.cell.serve_params, self._scratch,
-                self.pool, greqs, leases, bt_rows, chunk=self.chunk,
-                max_len=self.max_len, rng=self._rng, model=self.model,
-                accounting=self.cell.accounting,
-            )
-            t1 = self.rec.clock()
-            span_group(self.rec, "prefill", greqs, t0, t1, kind="warm",
-                       batch=len(group),
+            with self.rec.phase("prefill:warm") as ph:
+                toks, rows, self._rng, _b_pad = run_extend_group(
+                    self._extend, self.cell.serve_params, self._scratch,
+                    self.pool, greqs, leases, bt_rows, chunk=self.chunk,
+                    max_len=self.max_len, rng=self._rng, model=self.model,
+                    accounting=self.cell.accounting,
+                )
+            span_group(self.rec, "prefill", greqs, ph.ts, ph.ts + ph.dur,
+                       kind="warm", batch=len(group),
                        hit_tokens=sum(le.tokens for le in leases))
-            self.rec.record("prefill_s", t1 - t0)
+            self.rec.record("prefill_s", ph.dur)
             self.invocations += 1
             from repro.models.cache_utils import strip_kv_nodes
             for i, (req, tok) in enumerate(zip(greqs, toks)):

@@ -113,6 +113,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.telemetry import recorder_of
 from repro.models.cache_utils import (
     clean_arena_pages,
     dequantize_page,
@@ -1399,4 +1400,6 @@ def run_extend_group(extend_fn, params, scratch, pool: KVPool, reqs,
     pool.kv_scales = scales
     if accounting is not None and b_pad != B:
         accounting.record_counter("prefill_dummy_rows", b_pad - B)
-    return [int(t) for t in np.asarray(toks)], rows, rng, b_pad
+    with recorder_of(accounting).phase("prefill.wait", wait=True):
+        toks = np.asarray(toks)
+    return [int(t) for t in toks], rows, rng, b_pad

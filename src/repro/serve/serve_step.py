@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.telemetry import DISABLED, FlightRecorder, recorder_of
 from repro.models.model import Model
 
 F32 = jnp.float32
@@ -113,7 +114,8 @@ def build_extend_step(model: Model, temperature: float = 0.0) -> Callable:
 def run_prefill_prompts(step_fn: Callable, params, scratch_cache, prompts,
                         *, chunk: int, max_len: int, rng,
                         model: Optional[Model] = None,
-                        srcs: Optional[Sequence] = None):
+                        srcs: Optional[Sequence] = None,
+                        rec: FlightRecorder = DISABLED):
     """Bucket-pad B same-bucket prompts and run ONE jitted ``prefill_step``.
 
     All NON-EMPTY prompts must share a bucket (``bucket_len`` of each
@@ -126,8 +128,9 @@ def run_prefill_prompts(step_fn: Callable, params, scratch_cache, prompts,
     invocation is bit-equivalent to B single-row invocations.  ``model``
     + ``srcs`` (per-row source features or None) add the family-specific
     batch extras via ``Model.ranged_batch_extras`` (encdec source
-    features; {} for every other family).  Returns
-    (first_tokens list, B-row KV cache, advanced rng).
+    features; {} for every other family).  The sync on the first tokens
+    is ``rec``'s ``prefill.wait`` phase.  Returns (first_tokens list,
+    B-row KV cache, advanced rng).
     """
     B = len(prompts)
     s_pad = bucket_len(max(len(p) for p in prompts), chunk, max_len)
@@ -150,7 +153,9 @@ def run_prefill_prompts(step_fn: Callable, params, scratch_cache, prompts,
             list(srcs) if srcs is not None else [None] * B, max_len))
     rng, sub = jax.random.split(rng)
     toks, _logits, cache = step_fn(params, scratch_cache, batch, sub)
-    return [int(t) for t in np.asarray(toks)], cache, rng
+    with rec.phase("prefill.wait", wait=True):
+        toks = np.asarray(toks)
+    return [int(t) for t in toks], cache, rng
 
 
 def run_prefill_group(step_fn: Callable, params, scratch: Callable, reqs,
@@ -176,6 +181,7 @@ def run_prefill_group(step_fn: Callable, params, scratch: Callable, reqs,
     toks, cache, rng = run_prefill_prompts(
         step_fn, params, scratch(b_pad), prompts,
         chunk=chunk, max_len=max_len, rng=rng, model=model, srcs=srcs,
+        rec=recorder_of(accounting),
     )
     if accounting is not None and b_pad != B:
         accounting.record_counter("prefill_dummy_rows", b_pad - B)

@@ -3,7 +3,10 @@ and the Chrome trace export — plus the end-to-end guarantee that one
 request yields exactly ONE closed span tree in both colocated and
 disaggregated serving.
 """
+import glob
+import itertools
 import json
+import os
 
 import numpy as np
 import pytest
@@ -14,7 +17,9 @@ from repro.configs.base import smoke_config
 from repro.configs.registry import get_arch
 from repro.core import DeviceGrid, Supervisor
 from repro.core.accounting import CellAccounting, summarize_requests
+from repro.core import telemetry
 from repro.core.telemetry import (
+    DISABLED,
     DecisionAudit,
     EventLog,
     FlightRecorder,
@@ -81,6 +86,61 @@ def test_disabled_recorder_is_total_noop():
     assert len(rec.log) == 0 and rec.hists == {} and rec.open_spans == []
     # accounting=None resolves to the shared disabled recorder
     assert recorder_of(None).enabled is False
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs what opens and
+    closes, in order."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name):
+        log = self.log
+
+        class _Ann:
+            def __enter__(self):
+                log.append(("enter", name))
+
+            def __exit__(self, *exc):
+                log.append(("exit", name))
+
+        return _Ann()
+
+
+def test_phase_annotates_and_times_on_enabled_recorder(monkeypatch):
+    ann = _Annotations()
+    monkeypatch.setattr(telemetry, "TraceAnnotation", ann)
+    t = [10.0]
+    rec = FlightRecorder("cellA", clock=lambda: t[0])
+    with rec.phase("step") as ph:
+        t[0] = 10.5
+        with rec.phase("decode.wait", wait=True) as w:
+            t[0] = 10.75
+        t[0] = 11.0
+    assert ann.log == [("enter", "step"), ("enter", "decode.wait"),
+                       ("exit", "decode.wait"), ("exit", "step")]
+    assert (ph.ts, ph.dur) == (10.0, 1.0)
+    assert (w.ts, w.dur) == (10.5, 0.25)
+    assert rec.wait_s == 0.25                      # only the wait phase
+    assert len(rec.log) == 0                       # a phase alone is no span
+    # a site that keeps the phase records it with its times
+    rec.add_complete("step", ph.ts, ph.dur, busy=2, wait_s=w.dur)
+    (ev,) = rec.log
+    assert (ev["name"], ev["ts"], ev["dur"]) == ("step", 10.0, 1.0)
+    assert ev["attrs"] == {"busy": 2, "wait_s": 0.25}
+
+
+def test_disabled_recorder_phase_annotates_and_records_nothing(monkeypatch):
+    ann = _Annotations()
+    monkeypatch.setattr(telemetry, "TraceAnnotation", ann)
+    waited = DISABLED.wait_s
+    with DISABLED.phase("decode.wait", wait=True) as ph:
+        pass
+    assert ann.log == [("enter", "decode.wait"), ("exit", "decode.wait")]
+    assert (ph.ts, ph.dur) == (0.0, 0.0)
+    DISABLED.add_complete("step", ph.ts, ph.dur, busy=1)
+    assert len(DISABLED.log) == 0 and DISABLED.wait_s == waited
 
 
 def test_event_log_ring_is_bounded_and_counts_drops():
@@ -337,3 +397,179 @@ def test_daemon_audit_explains_actions():
     inst = [e for e in trace["traceEvents"] if e["ph"] == "i"]
     assert len(inst) == 1 and inst[0]["name"] == "mark_failed"
     assert trace["otherData"]["decision_audit"][0]["tick"] == 0
+
+
+# ---------------------------------------------------------------------------
+# the serving loop's phases
+# ---------------------------------------------------------------------------
+PAGE = 8
+
+
+def _count_refusals(bat, refused):
+    """Wrap the pool's admit so each PoolExhausted it raises is counted."""
+    from repro.serve.kvpool import PoolExhausted
+    admit = bat.pool.admit
+
+    def counting(*a, **kw):
+        try:
+            return admit(*a, **kw)
+        except PoolExhausted:
+            refused[0] += 1
+            raise
+
+    bat.pool.admit = counting
+
+
+def test_step_span_per_call_with_busy_admitted_blocked_wait(model_and_params):
+    """One ``step`` ring span per call, its attrs read against what the
+    call did.  The recorder's clock ticks once per read, so a wait phase
+    (one sync, no clock read inside) lasts exactly 1: ``wait_s`` is the
+    step's prefill groups plus its decode sync."""
+    model, params = model_and_params
+    acc = CellAccounting("solo")
+    tick = itertools.count()
+    acc.recorder.clock = lambda: float(next(tick))
+    # two slots, but pages for one request's worst case: the others block
+    bat = ContinuousBatcher(model, params, batch_slots=2, max_len=MAX_LEN,
+                            prefill_chunk=16, page_size=PAGE,
+                            pool_pages=MAX_LEN // PAGE, accounting=acc)
+    refused = [0]
+    _count_refusals(bat, refused)
+    reqs = _requests(model.cfg.vocab, [30, 30, 9], max_new=3)
+    for r in reqs:
+        bat.submit(r)
+    rec = acc.recorder
+    calls = blocked = 0
+    while bat.queue or any(r is not None for r in bat.slot_req):
+        before = {r.rid: (len(r.output), r.started_at) for r in reqs}
+        refused[0] = 0
+        rec.log.drain()
+        bat.step()
+        calls += 1
+        evs = rec.log.drain()
+        (st,) = [e for e in evs if e["name"] == "step"]
+        a = st["attrs"]
+        admitted = sum(1 for r in reqs if before[r.rid][1] is None
+                       and r.started_at is not None)
+        assert a["admitted"] == admitted
+        # each admission's prefill gives a first token, each decoded row one
+        assert a["busy"] == sum(len(r.output) - before[r.rid][0]
+                                for r in reqs) - admitted
+        assert a["blocked"] == refused[0]
+        groups = {(e["ts"], e["dur"]) for e in evs if e["name"] == "prefill"}
+        assert a["wait_s"] == len(groups) + (1 if a["busy"] else 0)
+        for e in evs:
+            if e["name"] in ("prefill", "decode_step"):
+                assert st["ts"] <= e["ts"]
+                assert e["ts"] + e["dur"] <= st["ts"] + st["dur"]
+        blocked += a["blocked"]
+    assert calls > 3 and blocked > 0
+    assert sum(r.started_at is not None for r in reqs) == len(reqs)
+
+
+def _served_with_phases(model, params, accounting):
+    """Three requests, the third sharing the first's prefix (a warm
+    extend), on two slots; the first step runs before the others come."""
+    bat = ContinuousBatcher(model, params, batch_slots=2, max_len=MAX_LEN,
+                            prefill_chunk=16, page_size=PAGE,
+                            accounting=accounting)
+    rng = np.random.RandomState(1)
+    vocab = model.cfg.vocab
+    pre = rng.randint(1, vocab, size=16).astype(np.int32)
+    tail = [rng.randint(1, vocab, size=n).astype(np.int32) for n in (3, 5)]
+    reqs = [Request(rid=0, prompt=np.concatenate([pre, tail[0]]),
+                    max_new_tokens=3),
+            Request(rid=1, prompt=rng.randint(1, vocab, size=11)
+                    .astype(np.int32), max_new_tokens=2),
+            Request(rid=2, prompt=np.concatenate([pre, tail[1]]),
+                    max_new_tokens=3)]
+    bat.submit(reqs[0])
+    bat.step()
+    bat.submit(reqs[1])
+    bat.submit(reqs[2])
+    return bat, reqs
+
+
+def test_phases_keep_request_and_decode_spans(model_and_params):
+    """The ``prefill``, ``decode_step`` and ``queue`` spans read as they
+    did before the phases: one ``prefill`` and one ``queue`` per request,
+    one ``decode_step`` per step that decoded, the same attrs, each
+    inside its ``step``."""
+    model, params = model_and_params
+    acc = CellAccounting("solo")
+    bat, reqs = _served_with_phases(model, params, acc)
+    bat.run_until_drained()
+    evs = list(acc.recorder.log)
+    steps = [e for e in evs if e["name"] == "step"]
+    decodes = [e for e in evs if e["name"] == "decode_step"]
+    prefills = [e for e in evs if e["name"] == "prefill"]
+    queues = [e for e in evs if e["name"] == "queue"]
+    assert len(steps) == bat.decode_invocations
+    assert len(decodes) == bat.decode_invocations
+    assert [d["attrs"] for d in decodes] == [{"busy": s["attrs"]["busy"]}
+                                             for s in steps]
+    assert sorted(e["trace_id"] for e in prefills) == [0, 1, 2]
+    assert sorted(e["trace_id"] for e in queues) == [0, 1, 2]
+    kinds = {e["trace_id"]: e["attrs"] for e in prefills}
+    assert kinds[0] == {"kind": "cold", "batch": 1}
+    assert kinds[1] == {"kind": "cold", "batch": 1}
+    assert kinds[2] == {"kind": "warm", "batch": 1, "hit_tokens": 16}
+    assert {tuple(sorted(e["attrs"])) for e in queues} == {
+        ("prefix_hit", "slot")}
+    for e in decodes + prefills:
+        assert any(s["ts"] <= e["ts"] and e["ts"] + e["dur"]
+                   <= s["ts"] + s["dur"] for s in steps), e["name"]
+    assert sum(s["attrs"]["admitted"] for s in steps) == len(reqs)
+
+
+def _inside(inner, outer):
+    return outer[0] <= inner[0] and inner[1] <= outer[1]
+
+
+def test_profiler_trace_nests_the_step_phases(model_and_params, tmp_path):
+    """A CPU profiler trace of a run: every phase on one host line, nested
+    step > admit > prefill:* > prefill.wait and step > decode_step >
+    decode.prep, decode.wait, with decode.post after decode_step."""
+    from jax.profiler import ProfileData
+
+    model, params = model_and_params
+    bat, _ = _served_with_phases(model, params, None)
+    untraced = bat.decode_invocations
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        bat.run_until_drained()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(tmp_path, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    names = {"step", "admit", "prefill:cold", "prefill:warm",
+             "prefill.wait", "decode_step", "decode.prep", "decode.wait",
+             "decode.post"}
+    lines = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.end_ns) for e in line.events
+                   if e.name in names]
+            if evs:
+                lines.append(evs)
+    (evs,) = lines                                  # one host line
+    by = {n: [(a, b) for m, a, b in evs if m == n] for n in names}
+    assert len(by["step"]) == bat.decode_invocations - untraced
+    prefills = by["prefill:cold"] + by["prefill:warm"]
+    assert len(by["prefill:cold"]) == 1 and len(by["prefill:warm"]) == 1
+    for n, parents in (("admit", by["step"]), ("decode_step", by["step"]),
+                       ("decode.post", by["step"]),
+                       ("prefill:cold", by["admit"]),
+                       ("prefill:warm", by["admit"]),
+                       ("prefill.wait", prefills),
+                       ("decode.prep", by["decode_step"]),
+                       ("decode.wait", by["decode_step"])):
+        for iv in by[n]:
+            assert any(_inside(iv, p) for p in parents), (n, iv)
+    assert len(by["prefill.wait"]) == len(prefills)
+    for step in by["step"]:
+        (dec,) = [iv for iv in by["decode_step"] if _inside(iv, step)]
+        (prep,) = [iv for iv in by["decode.prep"] if _inside(iv, dec)]
+        (wait,) = [iv for iv in by["decode.wait"] if _inside(iv, dec)]
+        (post,) = [iv for iv in by["decode.post"] if _inside(iv, step)]
+        assert prep[1] <= wait[0] and dec[1] <= post[0]
