@@ -9,8 +9,9 @@ what keeps them out.  Model-level token identity for moe / encdec-cross layouts 
 covered by ``tests/test_kvpool.py``; this file checks the kernels
 directly against their pure-jnp refs and a dense oracle, across head
 layouts (MHA / GQA / MQA), multi-layer arenas, ragged lengths, page
-sizes that do not divide the sequence length, sentinel pages, and int8
-per-(page, layer) scales.
+sizes that do not divide the sequence length, rows that span several
+blocks of pages (and a ragged last block), sentinel pages, bf16 queries,
+and int8 per-(page, layer) scales.
 """
 from __future__ import annotations
 
@@ -74,6 +75,14 @@ DECODE_CASES = [
     (2, 4, 4, 64, 1, 8, 4, (32, 17)),   # MHA, ragged, P does not divide len
     (3, 8, 2, 32, 3, 8, 4, (8, 29, 1)),  # GQA, multi-layer, sentinel tails
     (2, 4, 1, 16, 2, 16, 2, (5, 32)),   # MQA
+    # A grid step reads a block of pages; at these page sizes a block
+    # holds fewer pages than the table (8 f32 or 32 int8 pages of 8 KV
+    # heads, 64 MQA pages), so rows span several blocks.
+    # rows end mid-page and mid-block, the second row's later blocks are
+    # all past its length, the third fills its last block
+    (3, 16, 8, 128, 2, 16, 64, (700, 77, 1024)),
+    (2, 16, 8, 128, 1, 16, 20, (320, 150)),   # ragged last block (20 % 8)
+    (2, 4, 1, 128, 1, 16, 128, (2000, 1100)),  # MQA: the block at its cap
 ]
 
 
@@ -112,19 +121,61 @@ def test_paged_decode_int8_matches_ref(B, Hq, Hkv, Dh, L, P, n_log, kv_lens):
     assert _rel(ref, flt) < 0.15
 
 
+@pytest.mark.parametrize("arena", ["bf16", "int8"])
+@pytest.mark.parametrize("B,Hq,Hkv,Dh,L,P,n_log,kv_lens", DECODE_CASES)
+def test_paged_decode_bf16_queries_match_ref(B, Hq, Hkv, Dh, L, P, n_log,
+                                             kv_lens, arena):
+    # bf16 queries against bf16 or int8 pages, as the serving path runs
+    # them; the output is bf16, so it matches the float32 ref to within
+    # its rounding
+    keys = jax.random.split(jax.random.PRNGKey(9), 2)
+    k, v, bt = _build_arena(keys[0], B, Hkv, Dh, L, P, n_log, kv_lens)
+    sc = {}
+    if arena == "int8":
+        k, ks = quantize_page(k, keep_axes=(0, 1))
+        v, vs = quantize_page(v, keep_axes=(0, 1))
+        sc = {"k_scale": ks, "v_scale": vs}
+    else:
+        k, v = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
+    q = jax.random.normal(keys[1], (B, 1, Hq, Dh), jnp.bfloat16)
+    kv_len = jnp.asarray(kv_lens, jnp.int32)
+    li = jnp.int32(L - 1)
+    out = paged_decode_attention(q, k, v, bt, kv_len, li, **sc)
+    assert out.dtype == jnp.bfloat16
+    ref = paged_decode_attention_ref(q[:, 0].astype(jnp.float32), k, v, bt,
+                                     kv_len, li, **sc)
+    assert _rel(out[:, 0], ref) < 4e-3
+
+
 def test_paged_decode_fully_sentinel_row_is_finite():
     # A freed / width-trimmed slot maps nothing; its (discarded) output
-    # must still be finite so it cannot poison the batch.
-    k, v, bt = _build_arena(jax.random.PRNGKey(2), 2, 2, 16, 1, 8, 2,
-                                (16, 16))
-    bt = bt.at[1].set(jnp.full((2,), k.shape[0], jnp.int32))
-    q = jax.random.normal(jax.random.PRNGKey(3), (2, 1, 2, 16), jnp.float32)
-    out = paged_decode_attention(q, k, v, bt,
-                                 jnp.asarray([16, 1], jnp.int32), jnp.int32(0))
+    # must still be finite so it cannot poison the batch, and it reads
+    # nothing: the free row comes after rows whose pages the kernel has
+    # already fetched, and its output is 0, not what they left behind.
+    k, v, bt = _build_arena(jax.random.PRNGKey(2), 3, 2, 16, 1, 8, 2,
+                                (16, 16, 16))
+    bt = bt.at[2].set(jnp.full((2,), k.shape[0], jnp.int32))
+    q = jax.random.normal(jax.random.PRNGKey(3), (3, 1, 2, 16), jnp.float32)
+    kv_len = jnp.asarray([16, 16, 1], jnp.int32)
+    out = paged_decode_attention(q, k, v, bt, kv_len, jnp.int32(0))
     assert bool(jnp.all(jnp.isfinite(out)))
-    assert _rel(out[0], paged_decode_attention_ref(
-        q[:, 0], k, v, bt, jnp.asarray([16, 1], jnp.int32),
-        jnp.int32(0))[0]) < 2e-5
+    assert bool(jnp.all(out[2] == 0))
+    assert _rel(out[:2], paged_decode_attention_ref(
+        q[:, 0], k, v, bt, kv_len, jnp.int32(0))[:2, None]) < 2e-5
+
+
+def test_paged_decode_skips_unmapped_pages_below_length():
+    # Sentinel entries inside a row's length are no positions at all:
+    # the kernel reads nothing for them, wherever they sit in a block.
+    k, v, bt = _build_arena(jax.random.PRNGKey(7), 2, 8, 128, 1, 16, 32,
+                            (500, 300))
+    N = k.shape[0]
+    bt = bt.at[0, 3].set(N).at[0, 9].set(N).at[1, 8].set(N)
+    q = jax.random.normal(jax.random.PRNGKey(8), (2, 1, 16, 128), jnp.float32)
+    kv_len = jnp.asarray([500, 300], jnp.int32)
+    out = paged_decode_attention(q, k, v, bt, kv_len, jnp.int32(0))
+    ref = paged_decode_attention_ref(q[:, 0], k, v, bt, kv_len, jnp.int32(0))
+    assert _rel(out[:, 0], ref) < 2e-5
 
 
 EXTEND_CASES = [

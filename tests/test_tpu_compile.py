@@ -66,9 +66,7 @@ def _arena_args(one_chip, dtype):
     return arena, scales
 
 
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
-                         ids=["bf16", "int8"])
-def test_paged_decode_kernel_compiles(one_chip, dtype):
+def _lower_paged_decode(one_chip, dtype, rows, n_log):
     from repro.kernels.decode_attention.decode_attention import (
         paged_decode_attention_bhd,
     )
@@ -77,11 +75,32 @@ def test_paged_decode_kernel_compiles(one_chip, dtype):
     def f(q, k, v, bt, kv_len, layer, **sc):
         return paged_decode_attention_bhd(q, k, v, bt, kv_len, layer,
                                           interpret=False, **sc)
-    compiled = jax.jit(f).lower(
-        _sds(one_chip, (B, HQ, DH), jnp.bfloat16), arena, arena,
-        _sds(one_chip, (B, N_LOG), jnp.int32), _sds(one_chip, (B,), jnp.int32),
-        _sds(one_chip, (), jnp.int32), **scales).compile()
+    return jax.jit(f).lower(
+        _sds(one_chip, (rows, HQ, DH), jnp.bfloat16), arena, arena,
+        _sds(one_chip, (rows, n_log), jnp.int32),
+        _sds(one_chip, (rows,), jnp.int32),
+        _sds(one_chip, (), jnp.int32), **scales)
+
+
+@pytest.mark.parametrize("n_log", [16, 128])
+@pytest.mark.parametrize("rows", [2, 4])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+def test_paged_decode_kernel_compiles(one_chip, dtype, rows, n_log):
+    compiled = _lower_paged_decode(one_chip, dtype, rows, n_log).compile()
     assert _holds_kernel(compiled, "paged_decode_attention")
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+def test_paged_decode_kernel_size_does_not_grow_with_the_table(one_chip, dtype):
+    """The kernel walks a block's pages in loops and takes the KV heads in
+    batched products, so what every decode program lowers is the same
+    size whatever the block-table width and the pages per block (8 pages
+    at width 8 and 16 at 16; 16 bf16 or 32 int8 pages at 128)."""
+    sizes = [len(_lower_paged_decode(one_chip, dtype, 4, n).as_text())
+             for n in (8, 16, 128)]
+    assert max(sizes) <= 1.03 * min(sizes), sizes
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
